@@ -16,8 +16,8 @@ ring streams chunks through cache instead of materializing the full
 (`transfer_model.RingCollectiveGemm`) covers.
 
 MUST be run as its own process (python -m benchmarks.collective_bench):
-the device-count flag only takes effect before jax initializes.
-`kernel_bench.run()` shells out to it for exactly that reason.
+the device-count flag only takes effect before jax initializes, so no
+parent that has started JAX can run it as a child.
 """
 from __future__ import annotations
 
@@ -72,7 +72,6 @@ def run(out_path=DEFAULT_OUT) -> list[tuple[str, float, str]]:
     )
     from repro.kernels.mx_matmul import Epilogue
     from repro.launch.mesh import make_mesh
-    from repro.parallel.sharding import shard_map
 
     n_dev = len(jax.devices())
     if n_dev < 2:
@@ -84,7 +83,7 @@ def run(out_path=DEFAULT_OUT) -> list[tuple[str, float, str]]:
     record: dict = {"device_count": n_dev, "iters": ITERS, "modes": {}}
 
     def sm(fn, in_specs, out_specs):
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
     # ---- all-gather ⊗ matmul ----

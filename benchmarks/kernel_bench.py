@@ -22,9 +22,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
-import subprocess
-import sys
 import time
 from pathlib import Path
 
@@ -191,12 +188,9 @@ def run() -> list[tuple[str, float, str]]:
 
     # ---- quantized dtype sweep + BENCH_quant.json artifact ----
     rows.extend(quant_sweep())
-
-    # ---- collective GEMM rows + BENCH_collective.json artifact ----
-    # Runs in a subprocess: the 8-device host mesh needs
-    # --xla_force_host_platform_device_count set BEFORE jax initializes,
-    # and this process's jax is already up on one device.
-    rows.extend(_collective_rows())
+    # The collective GEMM rows need an 8-device host mesh, set before JAX
+    # starts: `python -m benchmarks.collective_bench` runs them in a
+    # process of their own (the 8-device leg of scripts/run_benches.py).
     return rows
 
 
@@ -334,40 +328,6 @@ def quant_sweep(
          "backend": "pallas_mx(interpret)", "dtypes": result}, indent=2))
     rows.append(("quant_artifact", 0.0, f"wrote_{out_path.name}"))
     return rows
-
-
-def _collective_rows() -> list[tuple[str, float, str]]:
-    root = Path(__file__).resolve().parent.parent
-    env = {**os.environ,
-           "PYTHONPATH": f"{root / 'src'}:{os.environ.get('PYTHONPATH', '')}"}
-    # Strip only the device-count flag (the bench sets its own 8); any other
-    # inherited XLA flags must stay so all rows run under the same compiler.
-    kept = [f for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")]
-    if kept:
-        env["XLA_FLAGS"] = " ".join(kept)
-    else:
-        env.pop("XLA_FLAGS", None)
-    try:
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.collective_bench"],
-            capture_output=True, text=True, timeout=900, cwd=root, env=env,
-        )
-    except (OSError, subprocess.TimeoutExpired) as e:
-        return [("collective_bench_ERROR", 0.0, type(e).__name__)]
-    if r.returncode != 0:
-        tail = (r.stderr or r.stdout).strip().splitlines()[-1:]
-        return [("collective_bench_ERROR", 0.0,
-                 tail[0].replace(",", ";") if tail else "nonzero_exit")]
-    rows = []
-    for line in r.stdout.splitlines():
-        parts = line.strip().split(",")
-        if len(parts) == 3 and parts[0] != "name":
-            try:
-                rows.append((parts[0], float(parts[1]), parts[2]))
-            except ValueError:
-                continue
-    return rows or [("collective_bench_ERROR", 0.0, "no_rows")]
 
 
 def main() -> None:

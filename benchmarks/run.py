@@ -12,9 +12,6 @@ Prints ``name,us_per_call,derived`` CSV (harness contract).
 """
 from __future__ import annotations
 
-import sys
-import traceback
-
 
 def main() -> None:
     from . import (
@@ -22,26 +19,12 @@ def main() -> None:
         table2_mx_vs_baseline, table3_area, table4_perf_energy,
     )
 
-    modules = [
-        ("table1", table1_transfers),
-        ("table2", table2_mx_vs_baseline),
-        ("table3", table3_area),
-        ("table4", table4_perf_energy),
-        ("kernels", kernel_bench),
-        ("roofline", roofline_report),
-    ]
     print("name,us_per_call,derived")
-    failed = 0
-    for name, mod in modules:
-        try:
-            for row_name, us, derived in mod.run():
-                print(f"{row_name},{us:.2f},{derived}")
-        except Exception as e:  # noqa: BLE001
-            traceback.print_exc()
-            print(f"{name}_ERROR,0,{type(e).__name__}")
-            failed += 1
-    if failed:
-        sys.exit(1)
+    # a phase that raises fails the run (nonzero exit, traceback)
+    for mod in (table1_transfers, table2_mx_vs_baseline, table3_area,
+                table4_perf_energy, kernel_bench, roofline_report):
+        for row_name, us, derived in mod.run():
+            print(f"{row_name},{us:.2f},{derived}")
 
 
 if __name__ == '__main__':

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
+from collections import Counter
 from typing import Dict, List, Optional, Tuple
 
 _DTYPE_BYTES = {
@@ -96,13 +97,18 @@ class CensusResult:
         }
 
 
-def normalize_cost_analysis(cost) -> dict:
-    """Version-skew shim: `compiled.cost_analysis()` returns a dict on new
-    jax but a one-element list of dicts on older releases.  Normalize to a
-    dict (like the CompilerParams / shard_map shims, one site owns this)."""
-    if isinstance(cost, list):
-        return cost[0] if cost else {}
-    return cost or {}
+_KERNEL_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%([A-Za-z_][\w-]*?)(?:\.\d+)*\s*=.*"
+    r'custom_call_target="tpu_custom_call"')
+
+
+def kernel_launches(hlo: str) -> Counter:
+    """Pallas kernels in a compiled TPU module, by kernel name: each
+    ``tpu_custom_call`` instruction is named after its kernel (the
+    ``name=`` of its ``pallas_call``).  Static count — a kernel inside a
+    scanned layer body counts once."""
+    return Counter(m.group(1) for line in hlo.splitlines()
+                   if (m := _KERNEL_RE.match(line)))
 
 
 _COMMENT_RE = re.compile(r"/\*.*?\*/")

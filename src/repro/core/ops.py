@@ -11,13 +11,15 @@ deployment decision:
                          TPU, or CPU via interpret=True (tests).
   - "pallas_baseline"  — the paper's baseline traffic pattern (no inter-k
                          buffering, unfused epilogue), for A/B comparisons.
-  - "xla"              — plain jnp ops.  Used for dry-run lowering (Pallas
-                         TPU kernels cannot lower on the CPU backend) and CPU
-                         smoke tests.  On real TPU, XLA's own matmul already
-                         implements MX-style accumulation internally — the
-                         Pallas kernels exist to *control* the tiling with
-                         the paper's calculus and to fuse beyond what XLA
-                         picks (see EXPERIMENTS.md §Perf).
+  - "xla"              — plain jnp ops and the `kernels/ref.py` oracles:
+                         the CPU path (tests, dry-run lowering — Pallas TPU
+                         kernels cannot lower on the CPU backend) and the
+                         reference the kernels are checked against.
+
+The default is not a flag: `MXPolicy()` takes the platform's backend
+(`default_backend()`), the compiled Pallas kernels on a TPU and the XLA
+path elsewhere, so CPU tests keep their semantics and a TPU never silently
+runs the references.  Interpret mode exists only off-TPU.
 
 Tile plans are cached per unique (policy, M, N, K, per-operand bytes): the
 planner's O(candidates³) search would otherwise rerun on every un-jitted
@@ -69,9 +71,10 @@ def _cached_plan(
     policy: "MXPolicy", M: int, N: int, K: int, elem_bytes: int,
     fused_epilogue_ops: int, b_bytes: Optional[int] = None,
     out_bytes: Optional[int] = None, b_sparse: bool = False,
+    gated: bool = False,
 ) -> TilePlan:
     """The planner runs once per unique (policy, M, N, K, per-operand
-    bytes) key; MXPolicy is a frozen dataclass, so it hashes by value.
+    bytes, gated) key; MXPolicy is a frozen dataclass, so it hashes by value.
     ``elem_bytes`` is the A-operand element size; quantized GEMMs key on
     their narrow b_bytes/out_bytes too, so an int8-weights plan never
     collides with the f32 plan for the same shape — and ``b_sparse`` keys
@@ -81,7 +84,8 @@ def _cached_plan(
 
         t = PallasGemmTiling(policy.bm, policy.bn, policy.bk,
                              accumulate_in_vmem=policy.backend != "pallas_baseline",
-                             fused_epilogue_ops=fused_epilogue_ops)
+                             fused_epilogue_ops=fused_epilogue_ops,
+                             gated=gated)
         p = GemmProblem(M, N, K, elem_bytes, b_bytes=b_bytes,
                         out_bytes=out_bytes, b_sparse=b_sparse)
         return TilePlan(
@@ -99,6 +103,7 @@ def _cached_plan(
         vmem_budget=policy.vmem_budget,
         accumulate_in_vmem=policy.backend != "pallas_baseline",
         fused_epilogue_ops=fused_epilogue_ops,
+        gated=gated,
     )
 
 
@@ -111,34 +116,51 @@ def plan_cache_clear() -> None:
     _cached_plan.cache_clear()
 
 
+def default_backend() -> str:
+    """The kernel backend this platform runs: the Pallas MX kernels on a
+    TPU, the XLA path everywhere else."""
+    return "pallas_mx" if jax.default_backend() == "tpu" else "xla"
+
+
 @dataclasses.dataclass(frozen=True)
 class MXPolicy:
-    backend: str = "xla"
+    backend: str = ""  # "" = the platform's: default_backend()
     vmem_budget: int = DEFAULT_VMEM_BUDGET
-    interpret: bool = True  # CPU container default; False on real TPU
+    # None = interpret exactly when not on a TPU (the Pallas interpreter is
+    # the kernels' CPU path); a TPU refuses interpret mode.
+    interpret: Optional[bool] = None
     # Fixed block shapes override the tile planner when set:
     bm: Optional[int] = None
     bn: Optional[int] = None
     bk: Optional[int] = None
 
     def __post_init__(self):
+        if not self.backend:
+            object.__setattr__(self, "backend", default_backend())
         if self.backend not in BACKENDS:
             raise ValueError(f"unknown backend {self.backend!r}; one of {BACKENDS}")
+        on_tpu = jax.default_backend() == "tpu"
+        if self.interpret is None:
+            object.__setattr__(self, "interpret", not on_tpu)
+        elif self.interpret and on_tpu:
+            raise ValueError("interpret mode is the kernels' CPU path; on a "
+                             "TPU they run compiled (interpret=False)")
 
     def plan(
         self, M: int, N: int, K: int, elem_bytes: int,
         fused_epilogue_ops: int = 0, *,
         b_bytes: Optional[int] = None, out_bytes: Optional[int] = None,
-        b_sparse: bool = False,
+        b_sparse: bool = False, gated: bool = False,
     ) -> TilePlan:
         """Tile plan for one GEMM.  ``elem_bytes`` is the A-operand element
         size (and the default for B/out); mixed-precision callers pass
         per-operand ``b_bytes`` / ``out_bytes`` so the plan's traffic model
         reports the quantized bytes and the LRU key separates policies.
         ``b_sparse`` prices the weight stream as a 2:4 compressed payload
-        + metadata (b_bytes/2 + 0.125 per dense element)."""
+        + metadata (b_bytes/2 + 0.125 per dense element); ``gated``
+        budgets the SwiGLU launch's second weight block and accumulator."""
         return _cached_plan(self, M, N, K, elem_bytes, fused_epilogue_ops,
-                            b_bytes, out_bytes, b_sparse)
+                            b_bytes, out_bytes, b_sparse, gated)
 
 
 _state = threading.local()
@@ -417,6 +439,52 @@ def _expand_sparse(qb, qg, b_meta, bg_meta):
     return qb, qg
 
 
+def _tp_mesh():
+    """The active collective policy when it spans several devices, else
+    None: the condition under which a Pallas call must be partitioned by
+    hand, since XLA cannot partition a Mosaic kernel."""
+    from ..parallel.sharding import current_collectives
+
+    coll = current_collectives()
+    return coll if coll is not None and coll.axis_size > 1 else None
+
+
+def per_device(fn, *args, specs=None):
+    """Call ``fn`` (which launches Pallas kernels) on every device of the
+    active multi-device collective policy, inside ``shard_map``.
+
+    ``specs(axis, size) -> (in_specs, out_specs)`` hands each device its own
+    shards — tensor parallelism with no communication inside ``fn``;
+    ``specs`` None, or returning None, replicates every operand (XLA
+    gathers them first) and computes the whole call on each device.
+    Inside, the policy is switched off, so ``fn``'s own dispatch runs the
+    single-device kernels.  With no such policy this is ``fn(*args)``."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.sharding import collective_policy
+
+    coll = _tp_mesh()
+    if coll is None:
+        return fn(*args)
+    spec = specs(coll.axis, coll.axis_size) if specs is not None else None
+    in_specs, out_specs = spec if spec else ((P(),) * len(args), P())
+
+    def local(*shards):
+        with collective_policy(coll.mesh, axis=coll.axis, enabled=False):
+            return fn(*shards)
+
+    return jax.shard_map(local, mesh=coll.mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _replicated(call, **operands):
+    """`per_device` with every operand replicated; None operands stay out
+    of the shard_map and reach ``call`` as None."""
+    names = [k for k, v in operands.items() if v is not None]
+    return per_device(lambda *arrs: call(**dict(zip(names, arrs))),
+                      *(operands[k] for k in names))
+
+
 def matmul(
     a: jax.Array,
     b: jax.Array,
@@ -425,15 +493,41 @@ def matmul(
     out_dtype=None,
     precision=None,
     abft=None,
+    b_transposed: bool = False,
 ) -> jax.Array:
     """D = A @ B through the MX dispatch.  a: (..., M, K), b: (K, N).
     ``precision`` (PrecisionPolicy or registry name; explicit only — the
     ambient use_precision() context applies to linear/grouped_matmul, not
     to raw matmuls) routes through the quantized path.  ``abft`` (config,
     True/False, or None for the ambient use_abft() context) turns on the
-    checksummed write-back on the pallas_mx backend."""
+    checksummed write-back on the pallas_mx backend.
+
+    ``b_transposed``: b is stored (N, K) and D = A @ B.T — the tied LM
+    head over an embedding table.  Both backends fold the transpose into
+    the contraction (no (K, N) copy); it takes no precision and no ABFT."""
     policy = policy or current_policy()
     out_dtype = out_dtype or a.dtype
+    if policy.backend != "xla" and _tp_mesh() is not None:
+        from jax.sharding import PartitionSpec as P
+
+        def call(a, b):
+            return matmul(a, b, policy=policy, out_dtype=out_dtype,
+                          precision=precision, abft=abft,
+                          b_transposed=b_transposed)
+
+        def column_parallel(ax, size):  # the tied head: vocab-sharded table
+            if not b_transposed or b.shape[0] % size:
+                return None
+            return (P(), P(ax, None)), P(*([None] * (a.ndim - 1)), ax)
+        return per_device(call, a, b, specs=column_parallel)
+    if b_transposed:
+        if precision is not None or abft:
+            raise ValueError("b_transposed takes no precision and no abft")
+        if policy.backend == "xla":
+            return jnp.dot(a, b.T, preferred_element_type=jnp.float32
+                           ).astype(out_dtype)
+        if policy.backend != "pallas_mx":
+            return matmul(a, b.T, policy=policy, out_dtype=out_dtype)
     prec = _effective_precision(resolve_precision(precision), a.dtype, b.dtype)
     if prec is not None:
         return linear(a, b, None, policy=policy, out_dtype=out_dtype,
@@ -443,12 +537,14 @@ def matmul(
 
     a2, lead = _flatten_leading(a)
     M, K = a2.shape
-    N = b.shape[-1]
+    N = b.shape[0 if b_transposed else -1]
     plan = policy.plan(M, N, K, a.dtype.itemsize)
     kw = dict(bm=plan.bm, bn=plan.bn, bk=plan.bk, out_dtype=out_dtype,
               interpret=policy.interpret)
-    cfg = _resolve_abft(abft)
-    if policy.backend == "pallas_mx":
+    cfg = None if b_transposed else _resolve_abft(abft)
+    if b_transposed:
+        out = mx_matmul_fused(a2, b, b_transposed=True, **kw)
+    elif policy.backend == "pallas_mx":
         if cfg is not None:
             out = _abft_fused_gemm(
                 a2, b, ep=Epilogue(), bias=None, residual=None, w_gate=None,
@@ -469,10 +565,12 @@ def _collective_linear(
 ):
     """Route one linear through the overlapped ring collective matmul.
 
-    Returns None when the problem is not eligible (ring size 1, shapes not
-    divisible, gated reduce-scatter) — the caller then falls back to the
-    serialized path.  Per-shard tile plans come from the same LRU cache as
-    the single-device dispatch (keyed on the *chunk* problem).
+    Returns None when the problem is not eligible (ring size 1, N or K
+    not divisible over the ring, gated reduce-scatter, sparse weights) —
+    the caller then falls back to the serialized path.  Rows that do not
+    divide over the ring are zero-padded onto it and sliced off after.
+    Per-shard tile plans come from the same LRU cache as the single-device
+    dispatch (keyed on the *chunk* problem).
 
     Quantization happens ONCE, globally, before shard_map: per-row /
     per-column scales are constant along K, so sharding the narrow payload
@@ -495,23 +593,24 @@ def _collective_linear(
     x2, lead = _flatten_leading(x)
     M, K = x2.shape
     N = w.shape[-1]
+    Mp = M + (-M) % P_  # rows padded onto the ring
     ep = Epilogue(
         activation=activation, bias=b is not None,
         residual=residual is not None, out_scale=out_scale,
     )
     if tp_mode == "allgather":
         # x M-sharded, w/bias N-sharded; output full-M, N-sharded.
-        if M % P_ or N % P_:
+        if N % P_:
             return None
-        m_loc, n_loc, k_loc = M // P_, N // P_, K
+        m_loc, n_loc, k_loc = Mp // P_, N // P_, K
         x_spec, w_spec = P(ax, None), P(None, ax)
         b_spec, r_spec = P(ax), P(None, ax)
         as_spec, bs_spec = P(ax, None), P(None, ax)
     else:
         # x K-sharded, w K-sharded; output M-sharded (reduce-scattered).
-        if ep.has_gate or M % P_ or K % P_:
+        if ep.has_gate or K % P_:
             return None
-        m_loc, n_loc, k_loc = M // P_, N, K // P_
+        m_loc, n_loc, k_loc = Mp // P_, N, K // P_
         x_spec, w_spec = P(None, ax), P(ax, None)
         b_spec, r_spec = P(None), P(ax, None)
         as_spec, bs_spec = P(None, None), P(None, None)  # K-invariant scales
@@ -524,13 +623,17 @@ def _collective_linear(
         # metas are always None here: sparse policies bailed out above
         x2, a_s, w, b_s, w_gate, bg_s, _, _ = _prepare_quantized(
             x2, w, w_gate, prec)
+    if Mp != M:  # zero rows (and zero row scales) produce zero rows
+        x2 = _pad_rc(x2, Mp, K)
+        a_s = None if a_s is None else _pad_rc(a_s, Mp, 1)
 
     # the per-*chunk* GEMM plan, LRU-cached like every other dispatch
     a_bytes = x2.dtype.itemsize
     plan = policy.plan(m_loc, n_loc, k_loc, a_bytes,
                        fused_epilogue_ops=ep.n_fused_ops,
                        b_bytes=w.dtype.itemsize,
-                       out_bytes=jnp.dtype(out_dtype).itemsize)
+                       out_bytes=jnp.dtype(out_dtype).itemsize,
+                       gated=w_gate is not None)
     cc_abft = None
     fault_t = None
     if abft_cfg is not None and policy.backend == "pallas_mx":
@@ -552,9 +655,9 @@ def _collective_linear(
     )
     res2 = None
     if residual is not None:
-        res2 = jnp.broadcast_to(
+        res2 = _pad_rc(jnp.broadcast_to(
             residual, (*lead, x.shape[-2], N) if lead else (M, N)
-        ).reshape(M, N)
+        ).reshape(M, N), Mp, N)
 
     in_specs, operands = [x_spec, w_spec], [x2, w]
     if b is not None:
@@ -605,6 +708,7 @@ def _collective_linear(
                         f"SDC persisted in {tp_mode} ring collective after "
                         f"{abft_cfg.max_retries} rerun attempt(s)",
                         flagged=(("ring", n),), attempts=abft_cfg.max_retries)
+    out = out[:M]
     if x.ndim > 2:
         out = out.reshape(*lead, x.shape[-2], N)
     return out
@@ -624,7 +728,6 @@ def _ring_caller(mesh, ax, P_, direction, cc, ep, tp_mode,
         ring_allgather_matmul,
         ring_matmul_reduce_scatter,
     )
-    from ..parallel.sharding import shard_map as _shard_map
 
     out_dtype = jnp.dtype(out_dtype_name)
 
@@ -646,7 +749,7 @@ def _ring_caller(mesh, ax, P_, direction, cc, ep, tp_mode,
         return ring_matmul_reduce_scatter(x_s, w_s, **kw)
 
     out_specs = (out_spec, P()) if cc.abft is not None else out_spec
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
         out_specs=out_specs, check_vma=False,
     ))
@@ -724,8 +827,16 @@ def linear(
                 abft_cfg=(_resolve_abft(abft)
                           if policy.backend == "pallas_mx" else None),
             )
+            coll.routed[tp_mode, "fallback" if out is None else "ring"] += 1
             if out is not None:
                 return out
+    if policy.backend != "xla" and _tp_mesh() is not None:
+        return _replicated(
+            functools.partial(
+                linear, activation=activation, out_scale=out_scale,
+                policy=policy, out_dtype=out_dtype, precision=precision,
+                abft=abft),
+            x=x, w=w, b=b, w_gate=w_gate, residual=residual)
 
     if policy.backend == "pallas_mx":
         x2, lead = _flatten_leading(x)
@@ -748,7 +859,8 @@ def linear(
                            fused_epilogue_ops=ep.n_fused_ops,
                            b_bytes=w.dtype.itemsize,
                            out_bytes=jnp.dtype(out_dtype).itemsize,
-                           b_sparse=b_meta is not None)
+                           b_sparse=b_meta is not None,
+                           gated=w_gate is not None)
         res2 = None
         if residual is not None:
             res2 = jnp.broadcast_to(
@@ -836,6 +948,12 @@ def grouped_matmul(
     """
     policy = policy or current_policy()
     out_dtype = out_dtype or x.dtype
+    if policy.backend != "xla" and _tp_mesh() is not None:
+        return _replicated(
+            functools.partial(
+                grouped_matmul, activation=activation, policy=policy,
+                out_dtype=out_dtype, precision=precision, abft=abft),
+            x=x, w=w, group_sizes=group_sizes, w_gate=w_gate)
     prec = _effective_precision(_ambient_precision(precision),
                                 x.dtype, w.dtype)
     if prec is not None and prec.out is not None:
@@ -871,7 +989,8 @@ def grouped_matmul(
                        fused_epilogue_ops=n_fused,
                        b_bytes=w.dtype.itemsize,
                        out_bytes=jnp.dtype(out_dtype).itemsize,
-                       b_sparse=b_meta is not None)
+                       b_sparse=b_meta is not None,
+                       gated=w_gate is not None)
     cfg = _resolve_abft(abft)
     b_sparse = (b_meta is not None and min(plan.bk, K) % 8 == 0
                 and cfg is None)

@@ -18,9 +18,10 @@ from typing import Iterable, List, Optional, Tuple
 
 from .transfer_model import GemmProblem, PallasGemmTiling
 
-# TPU v5e-ish VMEM budget we allow a single kernel working set to claim.
-# (Real VMEM is ~128 MiB; we keep headroom for double buffering: Pallas
-# prefetches the next block while computing, doubling the input footprint.)
+# VMEM a single GEMM launch may claim, counted as Mosaic allocates it
+# (kernels/vmem.gemm_vmem_bytes: double-buffered blocks, accumulators, dot
+# results).  Half of a TPU v5e core's 128 MiB; the kernel raises its scoped
+# limit to match (the compiler's default scope is only 16 MiB).
 DEFAULT_VMEM_BUDGET = 64 * 1024 * 1024
 
 MXU_DIM = 128  # systolic array edge
@@ -73,8 +74,8 @@ def plan_matmul_tiles(
     vmem_budget: int = DEFAULT_VMEM_BUDGET,
     accumulate_in_vmem: bool = True,
     max_block: int = 4096,
-    acc_bytes: int = 4,
     fused_epilogue_ops: int = 0,
+    gated: bool = False,
 ) -> TilePlan:
     """Search (bm, bn, bk) minimizing HBM traffic under the VMEM budget.
 
@@ -87,7 +88,9 @@ def plan_matmul_tiles(
     final-k write-back; the returned plan carries the resulting
     ``epilogue_saved_bytes`` credit.  The savings are tile-shape independent
     (2*M*N per op), so they don't perturb the search ordering — they change
-    what the roofline reports, not which tiles win.
+    what the roofline reports, not which tiles win.  ``gated`` (SwiGLU)
+    stages a second weight block and accumulator, which the VMEM budget
+    counts.
 
     Tie-breaks (in order): fewer grid steps (higher "SIMD ratio" — the
     paper's instruction-amortization argument), larger bk (longer
@@ -107,17 +110,15 @@ def plan_matmul_tiles(
             for bk in bk_cands:
                 tiling = PallasGemmTiling(
                     bm, bn, bk, accumulate_in_vmem=accumulate_in_vmem,
-                    fused_epilogue_ops=fused_epilogue_ops,
+                    fused_epilogue_ops=fused_epilogue_ops, gated=gated,
                 )
-                # Double-buffered inputs: Pallas pipelines the next (A, B)
-                # block DMA while the MXU consumes the current one.  A
-                # 2:4-sparse B stages compressed payload + metadata
-                # (b_stream_bytes), so sparse weights buy larger tiles
-                # under the same budget — the narrow-operand argument again.
-                vmem = round(
-                    2 * (bm * bk * p.a_elem_bytes + bk * bn * p.b_stream_bytes)
-                    + bm * bn * acc_bytes
-                )
+                # What Mosaic allocates: double-buffered blocks (Pallas
+                # pipelines the next block DMA while the MXU consumes the
+                # current one), accumulators, dot results.  A 2:4-sparse B
+                # stages compressed payload + metadata, so sparse weights
+                # buy larger tiles under the same budget — the
+                # narrow-operand argument again.
+                vmem = tiling.vmem_bytes(p)
                 if vmem > vmem_budget:
                     continue
                 traffic = tiling.hbm_bytes(p)

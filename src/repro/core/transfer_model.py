@@ -342,6 +342,9 @@ class PallasGemmTiling:
     still writes M*N exactly once, so each fused op saves a full 2*M*N
     round-trip (epilogue *operand* loads — bias N, residual M*N — happen in
     both versions and are not credited).
+
+    ``gated`` marks a SwiGLU launch, which stages a second (gate) weight
+    block and keeps a second accumulator — it doubles the B-side VMEM.
     """
 
     bm: int
@@ -350,6 +353,7 @@ class PallasGemmTiling:
     accumulate_in_vmem: bool = True
     c_is_zero: bool = True
     fused_epilogue_ops: int = 0
+    gated: bool = False
 
     def hbm_transfers(self, p: GemmProblem) -> Transfers:
         return mem_to_vrf(
@@ -372,22 +376,26 @@ class PallasGemmTiling:
         return round(t.a_down * p.a_elem_bytes + t.b_down * p.b_stream_bytes
                      + (t.cd_down + t.d_up) * ob)
 
-    def vmem_bytes(self, p: GemmProblem, acc_bytes: int = 4) -> int:
-        """Working set in VMEM: one A block, one B block, one accumulator.
+    def vmem_bytes(self, p: GemmProblem) -> int:
+        """Working set in VMEM as Mosaic allocates it
+        (`kernels.vmem.gemm_vmem_bytes`): double-buffered A, B (and gate)
+        and output blocks, the f32 accumulator(s), the body's f32 dot
+        results.
 
         This is the "area budget" analogue of the paper's 256 B buffer.
         Quantized operand blocks shrink the input footprint (per-operand
         bytes), which is exactly how narrow operands buy LARGER tiles under
         the same budget — the paper's more-MACs-per-cycle argument restated
         as more-tile-per-VMEM.  A sparse B block stages payload + metadata
-        (``b_stream_bytes``) and expands to dense only transiently at the
-        dot; the STAGED bytes are the resident footprint.
+        and expands to dense only at the dot.
         """
-        return round(
-            self.bm * self.bk * p.a_elem_bytes
-            + self.bk * self.bn * p.b_stream_bytes
-            + self.bm * self.bn * acc_bytes
-        )
+        from ..kernels.vmem import gemm_vmem_bytes
+
+        return gemm_vmem_bytes(
+            min(self.bm, p.M), min(self.bn, p.N), min(self.bk, p.K),
+            a_bytes=p.a_elem_bytes, b_bytes=p.b_elem_bytes,
+            out_bytes=p.out_elem_bytes, gated=self.gated,
+            b_sparse=p.b_sparse)
 
     def epilogue_saved_bytes(self, p: GemmProblem, out_bytes: Optional[int] = None) -> int:
         """HBM bytes the fused epilogue eliminates vs the unfused op graph:

@@ -20,9 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._compat import CompilerParams
-
 from .mx_matmul import _pad_to
+from .vmem import compiler_params, gemm_vmem_bytes
 
 
 def _baseline_kernel(a_ref, b_ref, o_ref, *, out_dtype):
@@ -74,9 +73,12 @@ def baseline_matmul(
         ],
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            gemm_vmem_bytes(bm_, bn_, bk_, a_bytes=a.dtype.itemsize,
+                            b_bytes=b.dtype.itemsize,
+                            out_bytes=jnp.dtype(out_dtype).itemsize)),
         interpret=interpret,
+        name="baseline_matmul",
     )(a_p, b_p)
     return out[:M, :N]

@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   nk: int, bq: int, bk: int, lq: int, lk: int, scale: float,
@@ -105,7 +103,7 @@ def mx_flash_attention(
             pltpu.VMEM((bq_, 1), jnp.float32),  # l — running normalizer
             pltpu.VMEM((bq_, d), jnp.float32),  # acc — the MX tile buffer
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

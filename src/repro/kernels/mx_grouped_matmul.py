@@ -33,11 +33,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 from .abft import AbftSpec
 from .mx_matmul import (abft_accumulate, abft_inject, abft_scratch,
                         abft_verify, apply_activation, dot_f32)
 from .sparse import expand_24
+from .vmem import compiler_params, gemm_vmem_bytes
 
 
 def make_group_metadata(
@@ -424,10 +424,16 @@ def mx_grouped_matmul(
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=compiler_params(
+            ("parallel", "arbitrary", "arbitrary"),
+            gemm_vmem_bytes(
+                bm_, bn_, bk_, a_bytes=x.dtype.itemsize,
+                b_bytes=w.dtype.itemsize,
+                out_bytes=jnp.dtype(out_dtype).itemsize, gated=has_gate,
+                b_sparse=b_sparse, a_scale=a_scale is not None,
+                b_scale=b_scale is not None)),
         interpret=interpret,
+        name="mx_grouped_matmul",
     )(grp, tile, first, starts, sizes, *operands)
     # Rows not owned by any group (beyond sum(sizes)) are zero-filled INSIDE
     # the launch: the metadata steers spare dummy slots at the uncovered
@@ -440,27 +446,10 @@ def mx_grouped_matmul(
 
 
 def _ragged_dot_f32(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
-    """Memory-safe ragged dot: `lax.ragged_dot` when available, else a
-    per-group masked-GEMM loop.  Never materializes a (T, K, N) per-row
-    weight gather (which would be terabytes at real MoE sizes)."""
-    gs = group_sizes.astype(jnp.int32)
-    if hasattr(jax.lax, "ragged_dot"):
-        return jax.lax.ragged_dot(
-            x, w, gs, preferred_element_type=jnp.float32
-        )
-    T = x.shape[0]
-    ends = jnp.cumsum(gs)
-    starts = ends - gs
-    rows = jnp.arange(T, dtype=jnp.int32)
-    out = jnp.zeros((T, w.shape[-1]), jnp.float32)
-    for g in range(w.shape[0]):  # G is static; each step is one dense GEMM
-        mask = (rows >= starts[g]) & (rows < ends[g])
-        out += jnp.where(
-            mask[:, None],
-            jnp.dot(x, w[g], preferred_element_type=jnp.float32),
-            0.0,
-        )
-    return out
+    """Memory-safe ragged dot (`lax.ragged_dot`): never materializes a
+    (T, K, N) per-row weight gather (terabytes at real MoE sizes)."""
+    return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32)
 
 
 def grouped_matmul_reference(

@@ -38,9 +38,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
 from .abft import AbftSpec
 from .sparse import expand_24
+from .vmem import compiler_params, gemm_vmem_bytes
 
 ACTIVATIONS = ("none", "relu", "gelu", "silu", "swiglu")
 
@@ -59,7 +59,8 @@ def apply_activation(x: jax.Array, activation: str) -> jax.Array:
     raise ValueError(f"unknown activation {activation!r}; one of {ACTIVATIONS}")
 
 
-def dot_f32(a_blk: jax.Array, b_blk: jax.Array) -> jax.Array:
+def dot_f32(a_blk: jax.Array, b_blk: jax.Array,
+            b_transposed: bool = False) -> jax.Array:
     """One tile FMA chain with f32 accumulation for ANY operand dtype — the
     multi-precision FPU datapath (§III): narrow operands in, wide
     accumulation out.  int8×int8 takes the exact int32 MAC path (the MXU's
@@ -67,15 +68,25 @@ def dot_f32(a_blk: jax.Array, b_blk: jax.Array) -> jax.Array:
     f32 first (quantized integer VALUES are the payload — dequant scales
     are applied downstream at the write-back, never here).  Used by every
     kernel body and by the unfused XLA reference so backends accumulate
-    identically."""
+    identically.  ``b_transposed``: b_blk is (N, K), contracted on its last
+    dim (the MXU's NT form — no transposed copy is made).
+
+    An f32 operand is multiplied at f32 precision (``HIGHEST``): the TPU's
+    default contracts f32 in bf16 passes, which would round every f32
+    weight to 8 mantissa bits.  Operands widened from bf16 or narrower
+    are exact in one pass, so they keep the default."""
+    dims = (((a_blk.ndim - 1,), (1 if b_transposed else 0,)), ((), ()))
     if a_blk.dtype == b_blk.dtype and jnp.issubdtype(a_blk.dtype, jnp.integer):
-        return jnp.dot(
-            a_blk, b_blk, preferred_element_type=jnp.int32
+        return jax.lax.dot_general(
+            a_blk, b_blk, dims, preferred_element_type=jnp.int32
         ).astype(jnp.float32)
+    f32_in = jnp.float32 in (a_blk.dtype, b_blk.dtype)
     if a_blk.dtype != b_blk.dtype or a_blk.dtype.itemsize < 2:
         a_blk = a_blk.astype(jnp.float32)
         b_blk = b_blk.astype(jnp.float32)
-    return jnp.dot(a_blk, b_blk, preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        a_blk, b_blk, dims, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if f32_in else None)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,7 +275,8 @@ def abft_scratch(abft: Optional[AbftSpec], bm: int, bn: int) -> list:
 
 
 def _fused_kernel(*refs, nk: int, out_dtype, epilogue: Epilogue,
-                  abft: Optional[AbftSpec] = None, b_sparse: bool = False):
+                  abft: Optional[AbftSpec] = None, b_sparse: bool = False,
+                  b_transposed: bool = False):
     """Kernel body.  refs layout (inputs, outputs, scratch):
     a, b, [b_meta], [b_gate], [bg_meta], [a_scale], [b_scale], [bg_scale],
     [bias], [residual], [fault_delta, fault_row, fault_col],
@@ -321,7 +333,7 @@ def _fused_kernel(*refs, nk: int, out_dtype, epilogue: Epilogue,
     a_blk = a_ref[...]
     b_blk = (expand_24(b_ref[...], bmeta_ref[...]) if b_sparse
              else b_ref[...])
-    acc_ref[...] += dot_f32(a_blk, b_blk)
+    acc_ref[...] += dot_f32(a_blk, b_blk, b_transposed)
     if accg_ref is not None:
         bg_blk = (expand_24(bg_ref[...], bgmeta_ref[...]) if b_sparse
                   else bg_ref[...])
@@ -377,7 +389,7 @@ def _pad_to(x: jax.Array, m0: int, m1: int) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=("epilogue", "bm", "bn", "bk", "out_dtype", "interpret",
-                     "abft", "b_sparse"),
+                     "abft", "b_sparse", "b_transposed"),
 )
 def mx_matmul_fused(
     a: jax.Array,
@@ -393,6 +405,7 @@ def mx_matmul_fused(
     b_sparse: bool = False,
     b_meta: Optional[jax.Array] = None,
     bg_meta: Optional[jax.Array] = None,
+    b_transposed: bool = False,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
@@ -432,7 +445,19 @@ def mx_matmul_fused(
     holds quantized values; per-column scales are constant along K, so
     pruning does not disturb them) but not with ``abft`` (checksum
     recovery needs dense weight slices — callers decompress first).
+
+    ``b_transposed``: b is stored (N, K) — a tied embedding table serving as
+    the LM head — and each (bn, bk) block is contracted on its last dim by
+    the MXU's NT matmul, so no transposed copy of the table is ever made.
+    Plain epilogues only (no gate, sparsity or ABFT).
     """
+    if b_transposed and (epilogue.has_gate or b_sparse or abft is not None
+                         or epilogue.b_scale):
+        raise ValueError("b_transposed supports plain (ungated, dense, "
+                         "unscaled, unchecked) GEMMs only")
+    b_stored = b
+    if b_transposed:
+        b = b.T  # logical (K, N) view for the shape checks below
     if a.ndim != 2 or b.ndim != 2:
         raise ValueError(f"mx_matmul expects 2-D operands, got {a.shape}, {b.shape}")
     if epilogue.has_gate != (b_gate is not None):
@@ -479,36 +504,48 @@ def mx_matmul_fused(
     if b_sparse and bk_ % 8 != 0:
         raise ValueError(f"2:4 sparse GEMM needs bk % 8 == 0, got {bk_}")
     a_p = _pad_to(a, bm_, bk_)
+    # N is padded only for ABFT, whose checksums sum every column of a
+    # tile.  Otherwise the last column block may run past N: its stray
+    # columns never reach the output (the write-back is masked), so a
+    # (128256, d) tied embedding table is read in place instead of being
+    # copied into a padded buffer on every call.
+    bn_pad = bn_ if abft is not None else 1
     # Sparse payload/metadata pad in their own compressed units: K % 8 == 0
     # and bk % 8 == 0 make the K-pad a multiple of 8, so the padded payload
     # stays exactly Kp/2 rows (and metadata Kp/8) — zero payload expands to
     # a zero dense block, so the degenerate padded metadata is harmless.
-    b_p = (_pad_to(b, bk_ // 2, bn_) if b_sparse
-           else _pad_to(b, bk_, bn_))
+    if b_transposed:  # mld.b of the stored (N, K) table, block (bn, bk)
+        b_p = _pad_to(b_stored, bn_pad, bk_)
+        Np = b_p.shape[0]
+        b_spec = pl.BlockSpec((bn_, bk_), lambda i, j, k: (j, k))
+    else:  # mld.b (payload when sparse)
+        b_p = (_pad_to(b, bk_ // 2, bn_pad) if b_sparse
+               else _pad_to(b, bk_, bn_pad))
+        Np = b_p.shape[1]
+        b_spec = pl.BlockSpec((bk_ // 2 if b_sparse else bk_, bn_),
+                              lambda i, j, k: (k, j))
     Mp, Kp = a_p.shape
-    Np = b_p.shape[1]
     nk = Kp // bk_
-    grid = (Mp // bm_, Np // bn_, nk)
-
+    grid = (Mp // bm_, pl.cdiv(Np, bn_), nk)
     in_specs = [
         pl.BlockSpec((bm_, bk_), lambda i, j, k: (i, k)),  # mld.a
-        pl.BlockSpec((bk_ // 2 if b_sparse else bk_, bn_),
-                     lambda i, j, k: (k, j)),  # mld.b (payload when sparse)
+        b_spec,
     ]
     operands = [a_p, b_p]
     scratch = [pltpu.VMEM((bm_, bn_), jnp.float32)]  # the tile buffer
     if b_sparse:
         # packed 2-bit indices ride the same (k, j) steering as the payload
         in_specs.append(pl.BlockSpec((bk_ // 8, bn_), lambda i, j, k: (k, j)))
-        operands.append(_pad_to(b_meta, bk_ // 8, bn_))
+        operands.append(_pad_to(b_meta, bk_ // 8, bn_pad))
     if epilogue.has_gate:
         in_specs.append(pl.BlockSpec((bk_ // 2 if b_sparse else bk_, bn_),
                                      lambda i, j, k: (k, j)))
-        operands.append(_pad_to(b_gate, bk_ // 2 if b_sparse else bk_, bn_))
+        operands.append(_pad_to(b_gate, bk_ // 2 if b_sparse else bk_,
+                                bn_pad))
         if b_sparse:
             in_specs.append(
                 pl.BlockSpec((bk_ // 8, bn_), lambda i, j, k: (k, j)))
-            operands.append(_pad_to(bg_meta, bk_ // 8, bn_))
+            operands.append(_pad_to(bg_meta, bk_ // 8, bn_pad))
         scratch.append(pltpu.VMEM((bm_, bn_), jnp.float32))
     if epilogue.a_scale:
         # (M, 1) per-row scale panel rides with the i tile (padded rows of
@@ -517,18 +554,19 @@ def mx_matmul_fused(
         operands.append(_pad_to(a_scale.astype(jnp.float32), bm_, 1))
     if epilogue.b_scale:
         in_specs.append(pl.BlockSpec((1, bn_), lambda i, j, k: (0, j)))
-        operands.append(_pad_to(b_scale.astype(jnp.float32), 1, bn_))
+        operands.append(_pad_to(b_scale.astype(jnp.float32), 1, bn_pad))
         if epilogue.has_gate:
             in_specs.append(pl.BlockSpec((1, bn_), lambda i, j, k: (0, j)))
-            operands.append(_pad_to(bg_scale.astype(jnp.float32), 1, bn_))
+            operands.append(_pad_to(bg_scale.astype(jnp.float32), 1,
+                                    bn_pad))
     if epilogue.bias:
         # (N,) -> (1, N): the bias block rides along with the (i, j) tile and
         # is consumed only at the final-k store.
         in_specs.append(pl.BlockSpec((1, bn_), lambda i, j, k: (0, j)))
-        operands.append(_pad_to(bias.reshape(1, -1), 1, bn_))
+        operands.append(_pad_to(bias.reshape(1, -1), 1, bn_pad))
     if epilogue.residual:
         in_specs.append(pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)))
-        operands.append(_pad_to(residual, bm_, bn_))
+        operands.append(_pad_to(residual, bm_, bn_pad))
     grid_m, grid_n = grid[0], grid[1]
     if inject:
         for arr, dt in ((fault_delta, jnp.float32), (fault_row, jnp.int32),
@@ -550,8 +588,14 @@ def mx_matmul_fused(
 
     kernel = functools.partial(
         _fused_kernel, nk=nk, out_dtype=out_dtype, epilogue=epilogue,
-        abft=abft, b_sparse=b_sparse,
+        abft=abft, b_sparse=b_sparse, b_transposed=b_transposed,
     )
+    vmem = gemm_vmem_bytes(
+        bm_, bn_, bk_, a_bytes=a.dtype.itemsize, b_bytes=b.dtype.itemsize,
+        out_bytes=jnp.dtype(out_dtype).itemsize, gated=epilogue.has_gate,
+        b_sparse=b_sparse, a_scale=epilogue.a_scale,
+        b_scale=epilogue.b_scale, bias=epilogue.bias,
+        residual_bytes=residual.dtype.itemsize if epilogue.residual else 0)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -559,10 +603,10 @@ def mx_matmul_fused(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary"), vmem),
         interpret=interpret,
+        name="mx_matmul_fused",
     )(*operands)
     if abft is not None:
         out, flags = out

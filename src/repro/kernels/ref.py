@@ -8,6 +8,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# The paged attention oracles compute in f32 and contract at f32 precision
+# on every backend (a TPU's default would use bf16 passes).
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def matmul_ref(a: jax.Array, b: jax.Array, out_dtype=None) -> jax.Array:
     """D = A @ B with f32 accumulation (the MX semantic: full-precision
@@ -113,7 +117,7 @@ def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, *,
     v = v.reshape(B, W * ps, Hkv, d)
 
     qh = q.astype(jnp.float32).reshape(B, Hkv, G, d)
-    s = jnp.einsum("bhgd,bshd->bhgs", qh, k,
+    s = jnp.einsum("bhgd,bshd->bhgs", qh, k, precision=_F32,
                    preferred_element_type=jnp.float32) / (d ** 0.5)
     kpos = jnp.arange(W * ps)[None, None, None, :]
     # free slots (length 0) attend to position 0 so the softmax stays
@@ -121,7 +125,7 @@ def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, *,
     keep = kpos < jnp.maximum(lengths, 1)[:, None, None, None]
     s = jnp.where(keep, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgs,bshd->bhgd", p, v,
+    o = jnp.einsum("bhgs,bshd->bhgd", p, v, precision=_F32,
                    preferred_element_type=jnp.float32)
     o = jnp.where(lengths[:, None, None, None] > 0, o, 0.0)
     return o.reshape(B, H, d).astype(q.dtype)
@@ -161,13 +165,13 @@ def paged_prefill_ref(q, k_pages, v_pages, page_table, index, *,
     v = v.reshape(B, W * ps, Hkv, d)
 
     qh = q.astype(jnp.float32).reshape(B, S, Hkv, G, d)
-    s = jnp.einsum("bqhgd,bshd->bhgqs", qh, k,
+    s = jnp.einsum("bqhgd,bshd->bhgqs", qh, k, precision=_F32,
                    preferred_element_type=jnp.float32) / (d ** 0.5)
     qpos = jnp.asarray(index)[:, None] + jnp.arange(S)  # (B, S)
     kpos = jnp.arange(W * ps)
     keep = kpos[None, None, :] <= qpos[:, :, None]      # (B, S, W*ps)
     s = jnp.where(keep[:, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgqs,bshd->bqhgd", p, v,
+    o = jnp.einsum("bhgqs,bshd->bqhgd", p, v, precision=_F32,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, S, H, d).astype(q.dtype)

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 
 def _ssd_kernel(x_ref, alog_ref, b_ref, c_ref, y_ref, h_ref, *, out_dtype):
     i = pl.program_id(0)
@@ -102,7 +100,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((chunk, P), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Lp, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((S, P), jnp.float32)],  # the carried state
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -17,6 +17,7 @@ CPU smoke examples:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import sys
 import time
 
@@ -25,24 +26,55 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import ARCH_IDS, get_config
+from ..launch.compile_cache import enable_compile_cache
 from ..launch.mesh import make_mesh
 from ..launch.steps import make_chunked_prefill_step, make_serve_step
 from ..models import build_model
-from ..parallel.sharding import make_rules, use_rules
+from ..parallel.sharding import (
+    collective_policy, make_rules, tree_shardings, use_rules,
+)
 
 
-def _run_continuous(model, cfg, params, args) -> int:
+def device_label() -> str:
+    """What the rates were measured on, as JAX reports it."""
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind} x{len(jax.devices())}"
+
+
+def place(rules, tree, axes):
+    """Put a pytree on the rules' mesh, each leaf sharded by its logical
+    axes (the same mapping `launch/train.py` places parameters with)."""
+    return jax.device_put(tree, tree_shardings(rules, tree, axes))
+
+
+def load_model(args, mesh=None):
+    """Config, model, sharding rules and parameters for ``args``.  The
+    parameters come from PRNGKey(0), created directly in their shardings
+    on ``mesh`` (default: a (1, n) tensor-parallel mesh over every device)
+    — no device holds a full copy on the way."""
+    cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
+    model = build_model(cfg)
+    if mesh is None:
+        mesh = make_mesh((1, len(jax.devices())), ("data", "model"))
+    rules = make_rules(mesh, profile=cfg.parallelism)
+    with use_rules(rules):
+        shardings = tree_shardings(rules, model.abstract(), model.axes())
+        params = jax.jit(model.init, out_shardings=shardings)(
+            jax.random.PRNGKey(0))
+    return cfg, model, rules, params
+
+
+def run_continuous(model, cfg, params, args, rules):
     """Continuous batching: 2x requests stream through --batch decode slots
-    (runtime/batcher.py).  --paged swaps the dense (slots, max_len) cache
-    for the page-pool backend (runtime/kv_pages + kernels/mx_flash_decode)
-    and reports the allocator's page occupancy.  --prefix-cache additionally
-    shares already-prefilled prompt prefixes across requests (every request
-    gets a common system prompt here, so hits are visible) and reports the
-    index's hit rate and pages shared.  --chaos additionally threads a
-    seeded `ChaosInjector` through every step (transient step failures,
-    one-slot logit poisoning, pool-pressure episodes, latency spikes) and
-    reports the per-step health record: typed finish reasons, retries,
-    preempt/resume counts, quarantines, straggler flags."""
+    (runtime/batcher.py); returns (batcher, finished requests, wall s).
+    The KV cache is placed on the rules' mesh like the parameters.
+    --paged swaps the dense (slots, max_len) cache for the page-pool
+    backend (runtime/kv_pages + kernels/mx_flash_decode).  --prefix-cache
+    additionally shares already-prefilled prompt prefixes across requests
+    (every request gets a common system prompt of 3/4 of --prompt-len
+    here, so hits are visible).  --chaos additionally threads a seeded
+    `ChaosInjector` through every step (transient step failures, one-slot
+    logit poisoning, pool-pressure episodes, latency spikes)."""
     from ..runtime.batcher import ContinuousBatcher, Request
     from ..runtime.lifecycle import ChaosConfig, ChaosInjector, RetryPolicy
 
@@ -98,6 +130,13 @@ def _run_continuous(model, cfg, params, args) -> int:
         chaos=chaos, retry=RetryPolicy(max_retries=3, backoff_s=0.0),
         speculate=args.speculate, drafter=drafter, abft=args.abft,
     )
+    if args.paged:
+        axes = model.make_paged_cache(batcher.pool.total_pages,
+                                      batcher.page_size, mode="axes",
+                                      kv_quant=kv_quant)
+    else:
+        axes = model.make_cache(B, max_len, mode="axes")
+    batcher.cache = place(rules, batcher.cache, axes)
     rng = np.random.default_rng(0)
     n_req = 2 * B
     # a shared system prompt (75% of prompt_len) + per-request tails: the
@@ -120,7 +159,16 @@ def _run_continuous(model, cfg, params, args) -> int:
                       deadline_steps=8 * (args.prompt_len + args.gen))
         batcher.submit(Request(rid=i, prompt=prompt, max_new=args.gen, **kw))
     finished = batcher.run_to_completion()
-    wall = time.time() - t0
+    return batcher, finished, time.time() - t0
+
+
+def _run_continuous(model, cfg, params, args, rules) -> int:
+    """`run_continuous`, then its report: page occupancy (--paged), the
+    prefix index's hit rate and pages shared (--prefix-cache), the
+    per-step health record (--chaos): typed finish reasons, retries,
+    preempt/resume counts, quarantines, straggler flags."""
+    batcher, finished, wall = run_continuous(model, cfg, params, args, rules)
+    B = args.batch
     total = sum(len(r.prompt) + len(r.output) for r in finished.values())
     mode = "paged" if args.paged else "dense"
     if args.prefix_cache:
@@ -132,7 +180,8 @@ def _run_continuous(model, cfg, params, args) -> int:
     if args.abft:
         mode += "+abft"
     print(f"continuous batching [{mode} cache]: {len(finished)} requests "
-          f"through {B} slots; {total / wall:.1f} tok/s (CPU)")
+          f"through {B} slots; {total / wall:.1f} tok/s "
+          f"({device_label()})")
     if args.paged:
         st = batcher.pool_stats()
         print(f"  pages: {st.pages_in_use} in use / {st.num_pages} pool "
@@ -242,7 +291,7 @@ def _run_disagg(model, cfg, params, args) -> int:
         mode += "+chaos"
     print(f"disagg serving [{mode}]: {len(finished)} requests, "
           f"{args.disagg} prefill workers -> {B} decode slots; "
-          f"{total / wall:.1f} tok/s (CPU)")
+          f"{total / wall:.1f} tok/s ({device_label()})")
     print(f"  handoffs: {s['handoffs_completed']} completed "
           f"({s['migrated_pages']} pages migrated, "
           f"{s['handoff_drops']} drops, {s['reroutes']} reroutes), "
@@ -266,7 +315,8 @@ def _run_disagg(model, cfg, params, args) -> int:
     return 0
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's flags, with the implied ones filled in."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true")
@@ -352,27 +402,33 @@ def main(argv=None):
     if args.kv_cache != "f32" and not args.paged:
         ap.error("--kv-cache int8 requires --paged (the quantized cache "
                  "lives in the page pool)")
+    return args
 
-    cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    cfg, model, rules, params = load_model(args)
     if cfg.model_kind == "encdec":
         print("enc-dec serving: decoder decode against a fixed encoder memory")
-    model = build_model(cfg)
-    mesh = make_mesh((1, len(jax.devices())), ("data", "model"))
-    rules = make_rules(mesh, profile=cfg.parallelism)
     max_len = args.max_len or (args.prompt_len + args.gen)
+    # over several devices the TP projections ride the ring collective
+    # matmuls, and every Pallas call is partitioned explicitly
+    tp = (collective_policy(rules.mesh) if rules.mesh.shape["model"] > 1
+          else contextlib.nullcontext())
 
-    with use_rules(rules):
-        params = model.init(jax.random.PRNGKey(0))
+    with use_rules(rules), tp:
         B = args.batch
         rng = np.random.default_rng(0)
 
         if args.disagg and cfg.model_kind != "encdec":
             return _run_disagg(model, cfg, params, args)
         if (args.continuous or args.paged) and cfg.model_kind != "encdec":
-            return _run_continuous(model, cfg, params, args)
+            return _run_continuous(model, cfg, params, args, rules)
 
         prompt = rng.integers(0, cfg.vocab, (B, args.prompt_len)).astype(np.int32)
-        cache = model.make_cache(B, max_len, mode="init")
+        cache = place(rules, model.make_cache(B, max_len, mode="init"),
+                      model.make_cache(B, max_len, mode="axes"))
         serve = make_serve_step(model, cfg)
         if cfg.model_kind == "encdec":
             frames = jnp.asarray(
@@ -419,7 +475,7 @@ def main(argv=None):
     gen = np.concatenate(out_tokens, axis=1)
     total_tokens = B * (args.prompt_len + args.gen)
     print(f"generated {gen.shape} tokens; "
-          f"{total_tokens / wall:.1f} tok/s (batch {B}, CPU)")
+          f"{total_tokens / wall:.1f} tok/s (batch {B}, {device_label()})")
     print("sample:", gen[0][:16].tolist())
     return 0
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from ..checkpoint.manager import CheckpointManager
 from ..configs import ARCH_IDS, get_config
 from ..data.pipeline import SyntheticLM
+from ..launch.compile_cache import enable_compile_cache
 from ..launch.mesh import make_mesh
 from ..launch.steps import make_train_step
 from ..models import build_model
@@ -51,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--metrics-csv", default=None)
     ap.add_argument("--param-dtype", default="float32")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch + ("-smoke" if args.smoke else ""))
     ndev = len(jax.devices())

@@ -96,13 +96,12 @@ class Embedding(Module):
         return p["table"][ids]
 
     def attend(self, p, x):
-        """Tied LM head: logits = x @ table^T (f32).
-
-        Stays on jnp.dot deliberately: XLA folds the transpose into the
-        dot_general's dimension numbers, whereas routing through the Pallas
-        path would materialize a full (D, V) copy of the table per call.
-        """
-        return jnp.dot(x, p["table"].T, preferred_element_type=jnp.float32)
+        """Tied LM head: logits = x @ table^T (f32), through the MX
+        dispatch with the table read in its stored (V, D) layout — the
+        kernel's NT matmul, or XLA's dot with the transpose folded into its
+        dimension numbers; neither makes a (D, V) copy."""
+        return ops.matmul(x, p["table"], out_dtype=jnp.float32,
+                          b_transposed=True)
 
 
 # ---------------------------------------------------------------------------
@@ -118,6 +117,31 @@ def _repeat_kv(k: jax.Array, groups: int) -> jax.Array:
     return jnp.broadcast_to(k[:, :, :, None, :], (b, s, h, groups, d)).reshape(
         b, s, h * groups, d
     )
+
+
+def _paged_kernel(kernel, q, cache, page_table, lengths, interpret):
+    """``kernel`` (`mx_flash_decode` / `mx_flash_verify`) over the cache's
+    page pools.  On a tensor-parallel mesh every device attends with its
+    own heads: query heads (axis -2 of q) and the pools' kv heads shard
+    together over the policy axis, so no device gathers another's pages."""
+    from jax.sharding import PartitionSpec as P
+
+    scales = [cache[k] for k in ("k_scale", "v_scale") if k in cache]
+
+    def call(q, k_pages, v_pages, table, lens, *sc):
+        k_scale, v_scale = sc if sc else (None, None)
+        return kernel(q, k_pages, v_pages, table, lens, k_scale=k_scale,
+                      v_scale=v_scale, interpret=interpret)
+
+    def heads(ax, size):
+        if cache["k_pages"].shape[2] % size:
+            return None
+        qs, pages = P(*[None] * (q.ndim - 2), ax, None), P(None, None, ax, None)
+        return ((qs, pages, pages, P(), P(), *[P(None, None, ax)] * len(scales)),
+                qs)
+
+    return ops.per_device(call, q, cache["k_pages"], cache["v_pages"],
+                          page_table, lengths, *scales, specs=heads)
 
 
 def full_attention(q, k, v, *, causal: bool, q_offset: int = 0) -> jax.Array:
@@ -449,16 +473,15 @@ class Attention(Module):
         offs = idx_b % ps
         cache = self._write_kv_pages(cache, page_ids, offs,
                                      k_new[:, 0], v_new[:, 0])  # (B, Hkv, hd)
-        kw = dict(
-            k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"))
         policy = ops.current_policy()
         if policy.backend == "pallas_mx":
-            o = mx_flash_decode(q[:, 0], cache["k_pages"], cache["v_pages"],
-                                page_table, lengths,
-                                interpret=policy.interpret, **kw)
+            o = _paged_kernel(mx_flash_decode, q[:, 0], cache, page_table,
+                              lengths, policy.interpret)
         else:
             o = paged_decode_ref(q[:, 0], cache["k_pages"], cache["v_pages"],
-                                 page_table, lengths, **kw)
+                                 page_table, lengths,
+                                 k_scale=cache.get("k_scale"),
+                                 v_scale=cache.get("v_scale"))
         o = o.reshape(b, 1, self.n_heads * self.hd)
         out = ops.linear(o, p["wo"], residual=residual, out_dtype=x.dtype,
                          tp_mode="reduce_scatter", precision=self.precision)
@@ -524,9 +547,8 @@ class Attention(Module):
             k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"))
         policy = ops.current_policy()
         if policy.backend == "pallas_mx":
-            o = mx_flash_verify(q, cache["k_pages"], cache["v_pages"],
-                                page_table, lengths,
-                                interpret=policy.interpret, **kw)
+            o = _paged_kernel(mx_flash_verify, q, cache, page_table, lengths,
+                              policy.interpret)
         else:
             # the causal window mask of the prefill oracle IS the verify
             # mask (row r at position lengths-S+r); free slots (length 0)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.quant import dequantize, quantize_int8_tensor as quantize  # noqa: F401
-from ..parallel.sharding import shard_map
 
 
 def compress_with_feedback(g: jax.Array, err: jax.Array):
@@ -54,7 +53,7 @@ def compressed_grad_sync(grads: Any, err_state: Any, mesh, axis: str = "pod"):
             return total / n, new_err
 
         spec = P()  # leaf replicated over `axis`; other axes untouched here
-        return shard_map(
+        return jax.shard_map(
             inner, mesh=mesh,
             in_specs=(spec, spec), out_specs=(spec, spec),
             check_vma=False,

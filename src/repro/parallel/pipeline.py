@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .sharding import shard_map
-
 
 def gpipe_apply(
     stage_fn: Callable,
@@ -86,7 +84,7 @@ def gpipe_apply(
     pspec_params = jax.tree.map(lambda _: P(axis), stage_params)
     in_specs = (pspec_params, P())
     out_specs = P()
-    return shard_map(
+    return jax.shard_map(
         shard_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(stage_params, x_microbatches)

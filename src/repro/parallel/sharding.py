@@ -20,6 +20,7 @@ import contextlib
 import dataclasses
 import threading
 import warnings
+from collections import Counter
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
@@ -27,18 +28,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AxisVal = Union[None, str, Tuple[str, ...]]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-skew shim: `jax.shard_map(..., check_vma=...)` on new jax,
-    `jax.experimental.shard_map.shard_map(..., check_rep=...)` on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 
 @dataclasses.dataclass
@@ -205,12 +194,20 @@ class CollectivePolicy:
     When active (see `collective_policy()`), `core.ops.linear(...,
     tp_mode=...)` routes eligible projections through the ring
     all-gather⊗matmul / matmul⊗reduce-scatter paths over `axis`, instead
-    of letting GSPMD insert serialized collectives around the GEMM."""
+    of letting GSPMD insert serialized collectives around the GEMM.
+
+    ``routed`` counts, per traced projection, which path it took:
+    (tp_mode, "ring"), or (tp_mode, "fallback") for one the ring could not
+    take (N or K not divisible over the ring, a gated reduce-scatter, a
+    sparse payload): GSPMD partitions it on the XLA backend, and a Pallas
+    kernel runs on replicated operands (`core.ops.per_device`)."""
 
     mesh: Mesh
     axis: str = "model"
     direction: str = "bidir"  # "fwd" | "bwd" | "bidir"
     enabled: bool = True
+    routed: Counter = dataclasses.field(default_factory=Counter,
+                                        compare=False, repr=False)
 
     def __post_init__(self):
         if self.axis not in self.mesh.shape:

@@ -144,7 +144,7 @@ from repro.kernels.mx_collective_matmul import (
     serialized_allgather_matmul, serialized_matmul_psum)
 from repro.kernels.mx_matmul import Epilogue
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import collective_policy, shard_map
+from repro.parallel.sharding import collective_policy
 
 mesh = make_mesh((1, 8), ("data", "model"))
 PZ = 8
@@ -158,7 +158,7 @@ res = jnp.asarray(rng.normal(size=(M, N)), jnp.float32)
 cc = ChunkCompute(backend="xla")
 
 def sm(fn, in_specs, out_specs):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))
 
 # --- all-gather x matmul: both ring directions + bidirectional ---
@@ -268,6 +268,33 @@ with collective_policy(mesh, axis="model"):
 assert jnp.allclose(ym_coll, ym_plain, atol=3e-4)
 assert jnp.allclose(aux_c, aux_p, atol=1e-6)
 print("MOE_OK")
+
+# --- Pallas on the mesh: XLA cannot partition a Mosaic kernel, so under a
+# policy every kernel runs per device — paged decode attention on its own
+# heads, the tied head on its vocab shard, a ragged-M projection padded
+# onto the ring — and the result equals one device ---
+from repro.models.transformer import DecoderLM
+from repro.configs import get_config
+from repro.parallel.sharding import make_rules, tree_shardings, use_rules
+cfg = get_config("llama3.2-1b-smoke")
+lm = DecoderLM(cfg)
+lp = lm.init(jax.random.PRNGKey(4))
+pages = lm.make_paged_cache(9, 4, dtype=jnp.float32)
+tok = jnp.asarray([[3], [7]], jnp.int32)
+index, lens = jnp.asarray([5, 2], jnp.int32), jnp.asarray([6, 3], jnp.int32)
+table = jnp.asarray([[1, 2, 0], [3, 0, 0]], jnp.int32)
+pol = ops.MXPolicy(backend="pallas_mx", bm=16, bn=32, bk=16)
+mesh4 = make_mesh((1, 4), ("data", "model"))
+rules = make_rules(mesh4)
+with ops.use_policy(pol):
+    want, _ = lm.decode_step_paged(lp, tok, pages, index, table, lens)
+    with use_rules(rules), collective_policy(mesh4) as coll:
+        lp4 = jax.device_put(lp, tree_shardings(rules, lp, lm.axes()))
+        got, _ = jax.jit(lm.decode_step_paged)(lp4, tok, pages, index,
+                                               table, lens)
+assert jnp.allclose(got, want, atol=2e-4), float(jnp.abs(got - want).max())
+assert coll.routed[("allgather", "ring")] and not coll.routed[("allgather", "fallback")]
+print("PALLAS_MESH_OK")
 print("ALL_COLLECTIVE_OK")
 """
 

@@ -100,3 +100,32 @@ def test_policy_batched_lhs():
     want = jnp.einsum("bmk,kn->bmn", a, b)
     assert got.shape == (2, 8, 32)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_mx", "pallas_baseline"])
+def test_policy_dispatch_transposed_weights(backend):
+    """The tied LM head: weights stored (N, K) — N ragged against bn, so the
+    kernel's last column block runs past N — through every backend."""
+    from repro.core.ops import MXPolicy, matmul, use_policy
+
+    a = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 48))
+    table = jax.random.normal(jax.random.PRNGKey(1), (100, 48))
+    with use_policy(MXPolicy(backend=backend, bm=16, bn=32, bk=16)):
+        got = matmul(a, table, out_dtype=jnp.float32, b_transposed=True)
+    want = jnp.einsum("bmk,nk->bmn", a, table)
+    assert got.shape == (2, 8, 100)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+def test_default_policy_follows_platform(monkeypatch):
+    """No flag picks the kernels: the XLA path (and the interpreter for an
+    explicit Pallas policy) off-TPU; compiled Pallas on a TPU, which
+    refuses interpret mode."""
+    from repro.core.ops import MXPolicy
+
+    assert (MXPolicy().backend, MXPolicy().interpret) == ("xla", True)
+    assert MXPolicy(backend="pallas_mx").interpret is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert (MXPolicy().backend, MXPolicy().interpret) == ("pallas_mx", False)
+    with pytest.raises(ValueError, match="interpret"):
+        MXPolicy(backend="pallas_mx", interpret=True)

@@ -439,7 +439,7 @@ from repro.kernels.mx_matmul import Epilogue
 from repro.kernels.quant import quantize_operand, dequantize
 from repro.core.precision import QuantSpec
 from repro.launch.mesh import make_mesh
-from repro.parallel.sharding import collective_policy, shard_map
+from repro.parallel.sharding import collective_policy
 
 mesh = make_mesh((1, 8), ("data", "model"))
 PZ = 8
@@ -457,7 +457,7 @@ ref_ag = jax.nn.gelu(deq(qa, a_s) @ deq(qb, b_s) + bias) + res
 ref_rs = (deq(qa, a_s) @ deq(qb, b_s) + bias) + res
 
 def sm(fn, in_specs, out_specs):
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))
 
 TOL = 2e-5 * float(jnp.abs(ref_ag).max() + 1)

@@ -136,3 +136,22 @@ def test_input_specs_cover_all_cells():
             assert specs.args and specs.in_shardings
             n_live += 1
     assert n_live == 32
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits
+    at the fixed <repo>/.jax_cache."""
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(REPO / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -88,3 +88,19 @@ def test_energy_coefficients_physical():
     assert c["mem"] >= 0 and c["vrf"] >= 0 and c["mac"] >= 0
     if c["vrf"] > 0:
         assert c["mem"] + 1e-18 >= c["vrf"] * 0.5  # mem no cheaper than ~VRF
+
+
+def test_vmem_count_matches_the_compiler():
+    """The buffers `gemm_vmem_bytes` counts are what Mosaic allocates for a
+    TPU v5e (its "Scoped allocation with size ..." for these launches):
+    (512, 2048, 256) f32 is 17 MiB of double-buffered blocks plus the
+    accumulator, 25 MiB when SwiGLU-gated; the f32 dot results come on top
+    (4 MiB per accumulator)."""
+    from repro.kernels.vmem import MiB, gemm_vmem_bytes
+
+    kw = dict(a_bytes=4, b_bytes=4, out_bytes=4)
+    assert gemm_vmem_bytes(512, 2048, 256, **kw) == 17 * MiB + 4 * MiB
+    assert gemm_vmem_bytes(512, 2048, 256, gated=True, **kw) == 25 * MiB + 8 * MiB
+    # an (8, K) bf16 block pads to whole (8, 128) tiles, not 16 rows
+    assert gemm_vmem_bytes(8, 4096, 2048, a_bytes=2, b_bytes=2,
+                           out_bytes=2) == 32 * MiB + 320 * 1024 + 128 * 1024
