@@ -162,11 +162,54 @@ def run_continuous(model, cfg, params, args, rules):
     return batcher, finished, time.time() - t0
 
 
+def step_report(batcher, finished) -> list:
+    """The step and request records as report lines: mean host ms of each
+    phase of a step that ran the device, queue wait (first admission less
+    submission, from the requests' wall stamps) p50/p95, the prompt tokens
+    chunked prefill pushed through in a step (p95), and the mean work of a
+    step: admissions and prefill chunks, and for a step that ran the
+    device its table width (paged) and the tokens it emitted."""
+    steps = [h for h in batcher.health if h.active]
+    if not steps:
+        return []
+    phases = ("admit", "prepare", "dispatch", "wait", "emit")
+    mean_ms = {p: 1e3 * float(np.mean([getattr(h, p + "_s") for h in steps]))
+               for p in phases + ("dt",)}
+    waits = []
+    for r in finished.values():
+        kinds = [k for k, _ in r.events]
+        if "admitted" in kinds:
+            waits.append(1e3 * (r.event_times[kinds.index("admitted")]
+                                - r.event_times[kinds.index("submitted")]))
+    lines = [f"  step phases, mean ms over {len(steps)} device steps: "
+             + ", ".join(f"{p} {mean_ms[p]:.2f}" for p in phases)
+             + f" (whole step {mean_ms['dt']:.2f})"]
+    if waits:
+        p50, p95 = np.percentile(waits, [50, 95])
+        lines.append(f"  queue wait: p50 {p50:.2f} ms, p95 {p95:.2f} ms "
+                     f"over {len(waits)} admitted requests")
+    prefill = np.percentile([h.prefill_tokens for h in batcher.health], 95)
+    lines.append(f"  prefill tokens per step: p95 {prefill:.0f} "
+                 f"({batcher.prefill_tokens} in {batcher.prefill_launches} "
+                 f"launches)")
+    mean = {f: float(np.mean([getattr(h, f) for h in batcher.health]))
+            for f in ("admitted", "prefill_chunks")}
+    mean.update({f: float(np.mean([getattr(h, f) for h in steps]))
+                 for f in ("width", "emitted")})
+    lines.append(f"  per step: {mean['admitted']:.2f} admitted, "
+                 f"{mean['prefill_chunks']:.2f} prefill chunks; per device "
+                 f"step: {mean['emitted']:.2f} tokens emitted"
+                 + (f", table width {mean['width']:.1f} pages"
+                    if batcher.paged else ""))
+    return lines
+
+
 def _run_continuous(model, cfg, params, args, rules) -> int:
-    """`run_continuous`, then its report: page occupancy (--paged), the
-    prefix index's hit rate and pages shared (--prefix-cache), the
-    per-step health record (--chaos): typed finish reasons, retries,
-    preempt/resume counts, quarantines, straggler flags."""
+    """`run_continuous`, then its report: the step records (`step_report`),
+    page occupancy (--paged), the prefix index's hit rate and pages shared
+    (--prefix-cache), the per-step health record (--chaos): typed finish
+    reasons, retries, preempt/resume counts, quarantines, straggler
+    flags."""
     batcher, finished, wall = run_continuous(model, cfg, params, args, rules)
     B = args.batch
     total = sum(len(r.prompt) + len(r.output) for r in finished.values())
@@ -182,6 +225,8 @@ def _run_continuous(model, cfg, params, args, rules) -> int:
     print(f"continuous batching [{mode} cache]: {len(finished)} requests "
           f"through {B} slots; {total / wall:.1f} tok/s "
           f"({device_label()})")
+    for line in step_report(batcher, finished):
+        print(line)
     if args.paged:
         st = batcher.pool_stats()
         print(f"  pages: {st.pages_in_use} in use / {st.num_pages} pool "

@@ -59,7 +59,21 @@ The fault-tolerant lifecycle (runtime/lifecycle) on top of both:
     pressure drives the preemption path, and latency spikes feed the
     `StragglerDetector` watchdog;
   - a per-step `StepHealth` record (`health`, `health_summary()`)
-    surfaced by ``serve --chaos`` and benchmarks/chaos_bench.py.
+    surfaced by ``serve --paged`` and ``serve --chaos``.
+
+Every step is recorded on the host clock (`time.perf_counter()`, the
+clock the profiler stamps host spans with).  Each phase of a step —
+admit, prepare, dispatch, wait, emit — is a profiler span
+``batcher.<phase>`` inside ``batcher.step`` (with the step's number as
+metadata) and a duration in its `StepHealth`; each chunked prefill launch
+is a span ``batcher.prefill`` (with ``rid`` and ``tokens``) inside
+``batcher.admit``, and the `prefill_launches` / `prefill_tokens` totals
+count them.  Every `Request.log_event` carries a wall stamp too.  The
+spans cost about a microsecond each and are recorded only while the
+profiler runs; the records are always kept.  The jitted decode and
+prefill programs are the functions `DECODE_PROGRAM` and `PREFILL_PROGRAM`
+name (XLA modules ``jit_step_paged`` and ``jit_prefill_paged``), names
+that trace readers key on; tests/test_step_records.py lowers both.
 
 CPU-testable end to end with smoke configs (tests/test_batcher.py asserts
 outputs are identical to per-request isolated decoding; tests/test_kv_pages
@@ -76,6 +90,7 @@ from typing import Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels.abft import use_abft
 from .fault import DeviceFailure, StragglerDetector
@@ -87,7 +102,33 @@ from .lifecycle import (
 from .prefix_cache import PrefixIndex
 from .speculative import DraftProposer, NGramDrafter, SpecStats
 
-__all__ = ["ContinuousBatcher", "Request", "FinishReason"]
+__all__ = ["ContinuousBatcher", "Request", "FinishReason",
+           "DECODE_PROGRAM", "PREFILL_PROGRAM"]
+
+DECODE_PROGRAM = "step_paged"      # name of the jitted paged decode step
+PREFILL_PROGRAM = "prefill_paged"  # name of the jitted chunked prefill
+
+
+class _Phase:
+    """One phase of a step: the profiler span ``batcher.<name>`` and its
+    host seconds, added to ``StepHealth.<name>_s``."""
+
+    __slots__ = ("health", "field", "span", "t")
+
+    def __init__(self, health: StepHealth, name: str):
+        self.health = health
+        self.field = name + "_s"
+        self.span = TraceAnnotation("batcher." + name)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t
+        setattr(self.health, self.field,
+                getattr(self.health, self.field) + dt)
+        self.span.__exit__(*exc)
 
 
 class _Slot:
@@ -198,6 +239,7 @@ class ContinuousBatcher:
         self.prefill_chunk = int(prefill_chunk)
         self.cow_copies = 0
         self.prefill_launches = 0  # chunked prefill launches issued
+        self.prefill_tokens = 0    # prompt tokens those launches carried
 
         # lifecycle / fault state
         self.chaos = chaos
@@ -471,6 +513,7 @@ class ContinuousBatcher:
                 s.pos = 0
                 s.prompt_left = len(s.seq)
                 self._mark_admitted(s, req)
+            health.admitted += 1
 
     def _mark_admitted(self, s: _Slot, req: Request):
         now = self.steps_run
@@ -567,14 +610,16 @@ class ContinuousBatcher:
         table = jnp.asarray(table)
         while s.pos < end:
             c = min(self.prefill_chunk, end - s.pos)
-            toks = jnp.asarray(s.seq[s.pos:s.pos + c][None, :])
-            _, self.cache = self._prefill(
-                self.params, toks, self.cache,
-                jnp.asarray([s.pos], np.int32), table,
-            )
+            with TraceAnnotation("batcher.prefill", rid=s.req.rid, tokens=c):
+                toks = jnp.asarray(s.seq[s.pos:s.pos + c][None, :])
+                _, self.cache = self._prefill(
+                    self.params, toks, self.cache,
+                    jnp.asarray([s.pos], np.int32), table,
+                )
             s.pos += c
             s.prompt_left -= c
             self.prefill_launches += 1
+            self.prefill_tokens += c
             self.pool.set_length(i, s.pos)
 
     def _preempt_slot(self, i: int):
@@ -769,117 +814,143 @@ class ContinuousBatcher:
         return host
 
     def step(self) -> int:
-        """One batched decode step across all slots; returns #active slots."""
-        if self.speculate:
-            return self._step_speculative()
+        """One batched step across all slots — a decode step, or a
+        speculative verify window when ``speculate`` > 0; returns #active
+        slots.  Records the step's `StepHealth` and its phase spans."""
         now = self.steps_run
-        health = StepHealth(step=now)
-        t0 = time.perf_counter()
-        if self.chaos is not None:
-            self.chaos.begin_step(now, self.pool)
-        self._expire_running(health)
-        self._admit(health)
-        health.active = self.active
-        health.queued = len(self.queue)
-        if self.pool is not None:
-            health.pages_free = self.pool.pages_free
-        if self.active == 0:
-            self._flush_health(health, t0, ran_device_step=False)
-            return 0
-        tokens = np.zeros((self.B, 1), np.int32)
-        index = np.zeros((self.B,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s.free:
-                index[i] = 0
-                continue
-            req = s.req
-            if s.prompt_left > 0:  # prefill phase: feed the next seq token
-                tokens[i, 0] = s.seq[len(s.seq) - s.prompt_left]
-            else:  # decode phase: feed the last generated token
-                tokens[i, 0] = req.output[-1]
-            index[i] = s.pos
-        fail = self.chaos.wants_failure(now) if self.chaos else False
-        if self.paged:
-            for i, s in enumerate(self.slots):
-                if not s.free:
-                    self.pool.set_length(i, s.pos + 1)
-            w = self._active_width()
-            table = jnp.asarray(self.pool.page_table(self.B, w))
-            lengths = jnp.asarray(self.pool.lengths(self.B))
-            (logits, self.cache), health.retries = self._device_step(
-                (self.params, jnp.asarray(tokens), self.cache,
-                 jnp.asarray(index), table, lengths), fail)
-        else:
-            (logits, self.cache), health.retries = self._device_step(
-                (self.params, jnp.asarray(tokens), self.cache,
-                 jnp.asarray(index)), fail)
-        if self.abft:
-            # token derivation reads the VERIFIED host copy (np.argmax and
-            # jnp.argmax agree bitwise: both take the first maximal index)
-            last_host = self._abft_host_logits(logits[:, -1], now)
-            next_tok = np.argmax(last_host, axis=-1).astype(np.int32)
-        else:
-            next_tok = np.asarray(jnp.argmax(logits[:, -1], axis=-1),
-                                  np.int32)
-        finite = None
-        if self.guard:
-            last = np.array(logits[:, -1])  # copy: poisoning writes into it
-            if self.chaos is not None:
-                victim = self.chaos.poison_slot(
-                    now, [i for i, s in enumerate(self.slots) if not s.free])
-                if victim is not None:
-                    last[victim] = np.nan  # the fault the guard must catch
-            finite = np.isfinite(last).all(axis=-1)
-        for i, s in enumerate(self.slots):
-            if s.free:
-                continue
-            req = s.req
-            s.pos += 1
-            if finite is not None and not finite[i]:
-                # quarantine: ONLY this slot fails; its pages are released
-                # and nothing it produced this step is kept or published
-                health.poisoned.append(req.rid)
-                req.log_event("quarantined", now)
-                self._finish_slot(i, FinishReason.FAILED)
-                continue
-            # a slot that exhausted its page reservation (an over-long
-            # prompt) is truncated and evicted — capacity exhaustion must
-            # degrade, never crash the serving loop.  The dense rectangle
-            # has the same cap at max_len; the paged cap can be lower when
-            # the reservation was clipped to min(max_len, prompt + max_new).
-            out_of_room = self.paged and s.pos >= len(
-                self.pool.owned(i)) * self.page_size
-            if s.prompt_left > 1:
-                s.prompt_left -= 1  # still prefilling; ignore the logit
-                if out_of_room:
-                    self._finish_slot(i, FinishReason.TRUNCATED)
-                continue
-            if s.prompt_left == 1:
-                s.prompt_left = 0  # prompt done: this logit starts (or, on
-                req.state = RequestState.DECODE  # resume, continues) decode
-                if self.prefix is not None and not out_of_room:
-                    # the sequence's full pages are now immutable (decode
-                    # continues in later pages): publish them for reuse.
-                    # Pages the slot itself mounted shared dedup inside the
-                    # index (existing nodes win, no double pin).
-                    self.prefix.insert(s.seq, self.pool.owned(i))
-            req.output.append(int(next_tok[i]))
-            if req.first_token_at is None:
-                req.first_token_at = now
-                req.log_event("first_token", now)
-            hit_eos = req.eos_id is not None and req.output[-1] == req.eos_id
-            if hit_eos:
-                self._finish_slot(i, FinishReason.EOS)
-            elif len(req.output) >= req.max_new:
-                self._finish_slot(i, FinishReason.MAX_NEW)
-            elif s.pos >= self.max_len:
-                self._finish_slot(i, FinishReason.MAX_LEN)
-            elif out_of_room:
-                self._finish_slot(i, FinishReason.TRUNCATED)
-        self._flush_health(health, t0, ran_device_step=True)
+        health = StepHealth(step=now, t_start=time.perf_counter())
+        launches, prompt_tokens = self.prefill_launches, self.prefill_tokens
+        with TraceAnnotation("batcher.step", step=now):
+            with _Phase(health, "admit"):
+                if self.chaos is not None:
+                    self.chaos.begin_step(now, self.pool)
+                self._expire_running(health)
+                self._admit(health)
+            health.prefill_chunks = self.prefill_launches - launches
+            health.prefill_tokens = self.prefill_tokens - prompt_tokens
+            health.active = self.active
+            health.queued = len(self.queue)
+            if self.pool is not None:
+                health.pages_free = self.pool.pages_free
+            ran = self.active > 0
+            if ran:
+                if self.speculate:
+                    self._verify_window(health)
+                else:
+                    self._decode(health)
+            self._flush_health(health, ran_device_step=ran)
         return self.active
 
-    def _step_speculative(self) -> int:
+    def _decode(self, health: StepHealth):
+        """The plain step's device work and emission: each live slot feeds
+        one token (its next prompt token, or its last output) through the
+        decode program and takes the argmax of its logits."""
+        now = health.step
+        with _Phase(health, "prepare"):
+            tokens = np.zeros((self.B, 1), np.int32)
+            index = np.zeros((self.B,), np.int32)
+            for i, s in enumerate(self.slots):
+                if s.free:
+                    continue
+                req = s.req
+                if s.prompt_left > 0:  # prefill phase: feed the next token
+                    tokens[i, 0] = s.seq[len(s.seq) - s.prompt_left]
+                else:  # decode phase: feed the last generated token
+                    tokens[i, 0] = req.output[-1]
+                index[i] = s.pos
+            fail = self.chaos.wants_failure(now) if self.chaos else False
+            args = (self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(index))
+            if self.paged:
+                for i, s in enumerate(self.slots):
+                    if not s.free:
+                        self.pool.set_length(i, s.pos + 1)
+                health.width = self._active_width()
+                args += (jnp.asarray(self.pool.page_table(self.B,
+                                                          health.width)),
+                         jnp.asarray(self.pool.lengths(self.B)))
+        with _Phase(health, "dispatch"):
+            (logits, self.cache), health.retries = self._device_step(args,
+                                                                     fail)
+            last = logits[:, -1]
+            best = None if self.abft else jnp.argmax(last, axis=-1)
+        with _Phase(health, "wait"):
+            if self.abft:
+                # token derivation reads the VERIFIED host copy (np.argmax
+                # and jnp.argmax agree bitwise: both take the first maximal
+                # index)
+                last_host = self._abft_host_logits(last, now)
+                next_tok = np.argmax(last_host, axis=-1).astype(np.int32)
+            else:
+                next_tok = np.asarray(best, np.int32)
+            finite = None
+            if self.guard:
+                host = np.array(last)  # copy: poisoning writes into it
+                if self.chaos is not None:
+                    victim = self.chaos.poison_slot(
+                        now, [i for i, s in enumerate(self.slots)
+                              if not s.free])
+                    if victim is not None:
+                        host[victim] = np.nan  # the fault the guard catches
+                finite = np.isfinite(host).all(axis=-1)
+        with _Phase(health, "emit"):
+            for i, s in enumerate(self.slots):
+                if s.free:
+                    continue
+                req = s.req
+                s.pos += 1
+                if finite is not None and not finite[i]:
+                    # quarantine: ONLY this slot fails; its pages are
+                    # released and nothing it produced this step is kept
+                    # or published
+                    health.poisoned.append(req.rid)
+                    req.log_event("quarantined", now)
+                    self._finish_slot(i, FinishReason.FAILED)
+                    continue
+                # a slot that exhausted its page reservation (an over-long
+                # prompt) is truncated and evicted — capacity exhaustion
+                # must degrade, never crash the serving loop.  The dense
+                # rectangle has the same cap at max_len; the paged cap can
+                # be lower when the reservation was clipped to
+                # min(max_len, prompt + max_new).
+                out_of_room = self.paged and s.pos >= len(
+                    self.pool.owned(i)) * self.page_size
+                if s.prompt_left > 1:
+                    s.prompt_left -= 1  # still prefilling; ignore the logit
+                    if out_of_room:
+                        self._finish_slot(i, FinishReason.TRUNCATED)
+                    continue
+                if s.prompt_left == 1:
+                    s.prompt_left = 0  # prompt done: this logit starts (or,
+                    req.state = RequestState.DECODE  # on resume, continues)
+                    if self.prefix is not None and not out_of_room:
+                        # the sequence's full pages are now immutable
+                        # (decode continues in later pages): publish them
+                        # for reuse.  Pages the slot itself mounted shared
+                        # dedup inside the index (existing nodes win, no
+                        # double pin).
+                        self.prefix.insert(s.seq, self.pool.owned(i))
+                req.output.append(int(next_tok[i]))
+                health.emitted += 1
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                    req.log_event("first_token", now)
+                self._finish_if_done(i, s, req, req.output[-1] == req.eos_id,
+                                     out_of_room)
+
+    def _finish_if_done(self, i: int, s: _Slot, req: Request, hit_eos: bool,
+                        out_of_room: bool):
+        """Ends the slot's request when its last emission finished it."""
+        if hit_eos:
+            self._finish_slot(i, FinishReason.EOS)
+        elif len(req.output) >= req.max_new:
+            self._finish_slot(i, FinishReason.MAX_NEW)
+        elif s.pos >= self.max_len:
+            self._finish_slot(i, FinishReason.MAX_LEN)
+        elif out_of_room:
+            self._finish_slot(i, FinishReason.TRUNCATED)
+
+    def _verify_window(self, health: StepHealth):
         """One speculative verify step across all slots: a (B, k+1) token
         window through `verify_step_paged` in ONE launch, then host-side
         greedy-exact acceptance.
@@ -903,157 +974,146 @@ class ContinuousBatcher:
         finish path, the prefix-cache publish point, the non-finite
         quarantine and the retry policy mirror the plain step exactly, so
         the emitted argmax stream is bitwise-identical to speculate=0."""
-        now = self.steps_run
-        health = StepHealth(step=now)
-        t0 = time.perf_counter()
-        if self.chaos is not None:
-            self.chaos.begin_step(now, self.pool)
-        self._expire_running(health)
-        self._admit(health)
-        health.active = self.active
-        health.queued = len(self.queue)
-        health.pages_free = self.pool.pages_free
-        if self.active == 0:
-            self._flush_health(health, t0, ran_device_step=False)
-            return 0
+        now = health.step
         S = self.speculate + 1
-        tokens = np.zeros((self.B, S), np.int32)
-        index = np.zeros((self.B,), np.int32)
-        lengths = np.zeros((self.B,), np.int32)
-        meta = {}
-        for i, s in enumerate(self.slots):
-            if s.free:
-                continue
-            req = s.req
-            cap = len(self.pool.owned(i)) * self.page_size
-            was_prefill = s.prompt_left > 0
-            if was_prefill:
-                start = len(s.seq) - s.prompt_left
-                take = min(S, s.prompt_left, cap - s.pos)
-                tokens[i, :take] = s.seq[start:start + take]
-                completes = take == s.prompt_left
+        with _Phase(health, "prepare"):
+            tokens = np.zeros((self.B, S), np.int32)
+            index = np.zeros((self.B,), np.int32)
+            lengths = np.zeros((self.B,), np.int32)
+            meta = {}
+            for i, s in enumerate(self.slots):
+                if s.free:
+                    continue
+                req = s.req
+                cap = len(self.pool.owned(i)) * self.page_size
+                was_prefill = s.prompt_left > 0
+                if was_prefill:
+                    start = len(s.seq) - s.prompt_left
+                    take = min(S, s.prompt_left, cap - s.pos)
+                    tokens[i, :take] = s.seq[start:start + take]
+                    completes = take == s.prompt_left
+                else:
+                    take = 1
+                    tokens[i, 0] = req.output[-1]
+                    completes = True
+                kd = 0
+                drafts = ()
+                if completes:
+                    # drafts must stay inside the reservation, max_len and
+                    # the max_new budget — the clamp is what makes every
+                    # finish path land on the same token it lands on
+                    # without speculation (and "draft longer than remaining
+                    # room" degrade to a shorter window instead of
+                    # corrupting pages)
+                    kd = max(0, min(S - take,
+                                    min(cap, self.max_len) - s.pos - take,
+                                    req.remaining_new() - 1))
+                    if kd > 0:
+                        prop = np.asarray(
+                            self.drafter.propose(req.sequence(), kd),
+                            np.int32).reshape(-1)[:kd]
+                        kd = int(prop.size)
+                        if kd:
+                            tokens[i, take:take + kd] = prop
+                        drafts = tuple(int(t) for t in prop)
+                index[i] = s.pos
+                # the kernel's row-r mask is kpos <= lengths-S+r: passing
+                # pos+S makes row r attend exactly through its own position
+                lengths[i] = s.pos + S
+                meta[i] = (take, kd, drafts, completes, was_prefill, cap)
+            fail = self.chaos.wants_failure(now) if self.chaos else False
+            deepest = max(s.pos for s in self.slots if not s.free)
+            # window rows reach position pos+S-1, so the table must cover
+            # one window past the deepest slot (entries past a slot's owned
+            # pages render as the dump page — pad-row writes land there
+            # harmlessly)
+            health.width = _next_pow2(self.pool.pages_for(deepest + S))
+            args = (self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(index),
+                    jnp.asarray(self.pool.page_table(self.B, health.width)),
+                    jnp.asarray(lengths))
+        with _Phase(health, "dispatch"):
+            (logits, self.cache), health.retries = self._device_step(
+                args, fail, fn=self._verify)
+            self.spec.launches += 1
+            best = None if self.abft else jnp.argmax(logits, axis=-1)
+        with _Phase(health, "wait"):
+            if self.abft:
+                win_host = self._abft_host_logits(logits, now)
+                rows = np.argmax(win_host, axis=-1).astype(np.int32)
             else:
-                take = 1
-                tokens[i, 0] = req.output[-1]
-                completes = True
-            kd = 0
-            drafts = ()
-            if completes:
-                # drafts must stay inside the reservation, max_len and the
-                # max_new budget — the clamp is what makes every finish
-                # path land on the same token it lands on without
-                # speculation (and "draft longer than remaining room"
-                # degrade to a shorter window instead of corrupting pages)
-                kd = max(0, min(S - take,
-                                min(cap, self.max_len) - s.pos - take,
-                                req.remaining_new() - 1))
+                rows = np.asarray(best, np.int32)  # (B, S)
+            finite = None
+            if self.guard:
+                host = np.array(logits)  # copy: poisoning writes into it
+                if self.chaos is not None:
+                    victim = self.chaos.poison_slot(
+                        now, [i for i, s in enumerate(self.slots)
+                              if not s.free])
+                    if victim is not None:
+                        host[victim] = np.nan
+                finite = np.isfinite(host).all(axis=-1)  # (B, S)
+        with _Phase(health, "emit"):
+            for i, s in enumerate(self.slots):
+                if s.free:
+                    continue
+                req = s.req
+                take, kd, drafts, completes, was_prefill, cap = meta[i]
+                if finite is not None and not finite[i, :take + kd].all():
+                    health.poisoned.append(req.rid)
+                    req.log_event("quarantined", now)
+                    self._finish_slot(i, FinishReason.FAILED)
+                    continue
+                pos0 = s.pos
+                emitted: List[int] = []
+                a = 0  # drafts the model agreed with (pre-EOS-truncation)
+                if completes:
+                    emitted = [int(rows[i, take - 1])]
+                    for j in range(kd):
+                        if drafts[j] != emitted[-1]:
+                            break
+                        emitted.append(int(rows[i, take + j]))
+                        a += 1
+                hit_eos = False
+                if req.eos_id is not None:
+                    for j, t in enumerate(emitted):
+                        if t == req.eos_id:
+                            emitted = emitted[:j + 1]
+                            hit_eos = True
+                            break
+                a_kept = max(len(emitted) - 1, 0)
+                s.pos = pos0 + take + a_kept
+                if was_prefill:
+                    s.prompt_left -= take
+                self.pool.set_length(i, s.pos)
                 if kd > 0:
-                    prop = np.asarray(
-                        self.drafter.propose(req.sequence(), kd),
-                        np.int32).reshape(-1)[:kd]
-                    kd = int(prop.size)
-                    if kd:
-                        tokens[i, take:take + kd] = prop
-                    drafts = tuple(int(t) for t in prop)
-            index[i] = s.pos
-            # the kernel's row-r mask is kpos <= lengths-S+r: passing
-            # pos+S makes row r attend exactly through its own position
-            lengths[i] = s.pos + S
-            meta[i] = (take, kd, drafts, completes, was_prefill, cap)
-        fail = self.chaos.wants_failure(now) if self.chaos else False
-        deepest = max(s.pos for s in self.slots if not s.free)
-        # window rows reach position pos+S-1, so the table must cover one
-        # window past the deepest slot (entries past a slot's owned pages
-        # render as the dump page — pad-row writes land there harmlessly)
-        w = _next_pow2(self.pool.pages_for(deepest + S))
-        table = self.pool.page_table(self.B, w)
-        (logits, self.cache), health.retries = self._device_step(
-            (self.params, jnp.asarray(tokens), self.cache,
-             jnp.asarray(index), jnp.asarray(table), jnp.asarray(lengths)),
-            fail, fn=self._verify)
-        self.spec.launches += 1
-        if self.abft:
-            win_host = self._abft_host_logits(logits, now)
-            rows = np.argmax(win_host, axis=-1).astype(np.int32)  # (B, S)
-        else:
-            rows = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # (B, S)
-        finite = None
-        if self.guard:
-            host = np.array(logits)  # copy: poisoning writes into it
-            if self.chaos is not None:
-                victim = self.chaos.poison_slot(
-                    now, [i for i, s in enumerate(self.slots) if not s.free])
-                if victim is not None:
-                    host[victim] = np.nan
-            finite = np.isfinite(host).all(axis=-1)  # (B, S)
-        for i, s in enumerate(self.slots):
-            if s.free:
-                continue
-            req = s.req
-            take, kd, drafts, completes, was_prefill, cap = meta[i]
-            if finite is not None and not finite[i, :take + kd].all():
-                health.poisoned.append(req.rid)
-                req.log_event("quarantined", now)
-                self._finish_slot(i, FinishReason.FAILED)
-                continue
-            pos0 = s.pos
-            emitted: List[int] = []
-            a = 0  # drafts the model agreed with (pre-EOS-truncation)
-            if completes:
-                emitted = [int(rows[i, take - 1])]
-                for j in range(kd):
-                    if drafts[j] != emitted[-1]:
-                        break
-                    emitted.append(int(rows[i, take + j]))
-                    a += 1
-            hit_eos = False
-            if req.eos_id is not None:
-                for j, t in enumerate(emitted):
-                    if t == req.eos_id:
-                        emitted = emitted[:j + 1]
-                        hit_eos = True
-                        break
-            a_kept = max(len(emitted) - 1, 0)
-            s.pos = pos0 + take + a_kept
-            if was_prefill:
-                s.prompt_left -= take
-            self.pool.set_length(i, s.pos)
-            if kd > 0:
-                self.spec.windows += 1
-                self.spec.drafted += kd
-                self.spec.accepted += a
-                req.log_event(f"speculated:{a}/{kd}", now)
-            self.spec.emitted += len(emitted)
-            out_of_room = s.pos >= cap
-            if not completes:
-                if out_of_room:
-                    self._finish_slot(i, FinishReason.TRUNCATED)
-                continue
-            if was_prefill:
-                req.state = RequestState.DECODE
-                # plain-path publish condition, measured at the position
-                # the LAST PROMPT row landed (accepted drafts beyond it
-                # must not change whether the prefix publishes)
-                if self.prefix is not None and pos0 + take < cap:
-                    self.prefix.insert(s.seq, self.pool.owned(i))
-            req.output.extend(emitted)
-            if req.first_token_at is None:
-                req.first_token_at = now
-                req.log_event("first_token", now)
-            if hit_eos:
-                self._finish_slot(i, FinishReason.EOS)
-            elif len(req.output) >= req.max_new:
-                self._finish_slot(i, FinishReason.MAX_NEW)
-            elif s.pos >= self.max_len:
-                self._finish_slot(i, FinishReason.MAX_LEN)
-            elif out_of_room:
-                self._finish_slot(i, FinishReason.TRUNCATED)
-        self._flush_health(health, t0, ran_device_step=True)
-        return self.active
+                    self.spec.windows += 1
+                    self.spec.drafted += kd
+                    self.spec.accepted += a
+                    req.log_event(f"speculated:{a}/{kd}", now)
+                self.spec.emitted += len(emitted)
+                out_of_room = s.pos >= cap
+                if not completes:
+                    if out_of_room:
+                        self._finish_slot(i, FinishReason.TRUNCATED)
+                    continue
+                if was_prefill:
+                    req.state = RequestState.DECODE
+                    # plain-path publish condition, measured at the
+                    # position the LAST PROMPT row landed (accepted drafts
+                    # beyond it must not change whether the prefix
+                    # publishes)
+                    if self.prefix is not None and pos0 + take < cap:
+                        self.prefix.insert(s.seq, self.pool.owned(i))
+                req.output.extend(emitted)
+                health.emitted += len(emitted)
+                if req.first_token_at is None:
+                    req.first_token_at = now
+                    req.log_event("first_token", now)
+                self._finish_if_done(i, s, req, hit_eos, out_of_room)
 
-    def _flush_health(self, health: StepHealth, t0: float,
-                      ran_device_step: bool):
-        dt = time.perf_counter() - t0
+    def _flush_health(self, health: StepHealth, ran_device_step: bool):
+        dt = time.perf_counter() - health.t_start
         if self.chaos is not None:
             dt += self.chaos.latency_spike(health.step)
         health.dt_s = dt

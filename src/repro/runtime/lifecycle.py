@@ -7,10 +7,12 @@ batch or silently truncating requests.  This module holds the vocabulary
 the fault-aware `ContinuousBatcher` (runtime/batcher) speaks:
 
   - `Request` with a full lifecycle: priority, step-denominated TTFT /
-    total deadlines, cancellation, a per-request typed event log, and a
-    typed `finish_reason` replacing the old bare ``done`` flag.  Every
-    submitted request terminates with exactly one reason — "absent from
-    finished" is no longer a possible outcome.
+    total deadlines, cancellation, a per-request typed event log
+    (``events``: ``(kind, step)`` pairs, with the `time.perf_counter()`
+    stamp of each in ``event_times``), and a typed `finish_reason`
+    replacing the old bare ``done`` flag.  Every submitted request
+    terminates with exactly one reason — "absent from finished" is no
+    longer a possible outcome.
   - `ChaosInjector`: step-level fault injection for the SERVING loop
     (transient DeviceFailure, non-finite-logit poisoning of one slot,
     simulated pool pressure that seizes free pages for a few steps,
@@ -19,9 +21,11 @@ the fault-aware `ContinuousBatcher` (runtime/batcher) speaks:
     so a fault-free and an injected run decode *bitwise identical* tokens
     for every request the faults did not touch, which is what the chaos
     suite asserts (tests/test_lifecycle.py).
-  - `StepHealth`: the per-step watchdog record (wall time, queue depth,
-    pool headroom, retries, quarantines, preemptions, straggler flag)
-    surfaced through ``serve --chaos`` and benchmarks/chaos_bench.py.
+  - `StepHealth`: the per-step record (wall time and its split into the
+    step's phases, admissions and prefill work, decode table width,
+    tokens emitted, queue depth, pool headroom, retries, quarantines,
+    preemptions, straggler flag) surfaced through ``serve --paged`` and
+    ``serve --chaos``.
 
 Deadlines are denominated in BATCHER STEPS, not wall seconds: the step is
 the scheduler's clock tick, and a step-based budget makes expiry exactly
@@ -32,6 +36,7 @@ caller converting measured step time into a step budget).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -89,6 +94,7 @@ class Request:
     finished_at: Optional[int] = None
     preemptions: int = 0
     events: List[Tuple[str, int]] = dataclasses.field(default_factory=list)
+    event_times: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def done(self) -> bool:
@@ -106,7 +112,11 @@ class Request:
              np.asarray(self.output, np.int32)])
 
     def log_event(self, kind: str, step: int) -> None:
+        """Appends ``(kind, step)`` to ``events`` and its wall stamp
+        (`time.perf_counter()`, the profiler's host clock) to
+        ``event_times``, so the two lists stay aligned."""
         self.events.append((kind, step))
+        self.event_times.append(time.perf_counter())
 
     def remaining_new(self) -> int:
         return max(self.max_new - len(self.output), 0)
@@ -127,22 +137,42 @@ class RetryPolicy:
 
 @dataclasses.dataclass
 class StepHealth:
-    """One watchdog record per batcher step."""
+    """One record per batcher step.
+
+    ``dt_s`` is the whole step on the host clock, from ``t_start``
+    (`time.perf_counter()`).  The ``*_s`` fields split it into the phases
+    the profiler also sees as spans ``batcher.<phase>``: ``admit``
+    (expiry, admission, prefix lookup and COW, chunked prefill launches,
+    shedding), ``prepare`` (the step's token, index, table and length
+    arrays and their copies to the device), ``dispatch`` (enqueueing the
+    decode or verify program), ``wait`` (the host blocked on the step's
+    result) and ``emit`` (per-slot appends, finish checks, prefix
+    publish).  A launch the runtime cannot take yet (a prefill chunk in
+    ``admit``, the step's program in ``dispatch``) holds the host in its
+    phase until it can.  They sum to at most ``dt_s``; a step with no
+    live slot runs only ``admit``."""
 
     step: int
+    t_start: float = 0.0
     dt_s: float = 0.0
+    admit_s: float = 0.0
+    prepare_s: float = 0.0
+    dispatch_s: float = 0.0
+    wait_s: float = 0.0
+    emit_s: float = 0.0
     active: int = 0
     queued: int = 0
+    admitted: int = 0          # requests admitted or resumed in the step
+    prefill_chunks: int = 0    # chunked prefill launches in the step
+    prefill_tokens: int = 0    # prompt tokens those launches carried
+    width: int = 0             # decode page-table width (paged), in pages
+    emitted: int = 0           # answer tokens the step produced
     pages_free: Optional[int] = None
     retries: int = 0
     poisoned: List[int] = dataclasses.field(default_factory=list)   # rids
     preempted: List[int] = dataclasses.field(default_factory=list)  # rids
     shed: List[int] = dataclasses.field(default_factory=list)       # rids
     straggler: bool = False
-    chaos: List[str] = dataclasses.field(default_factory=list)
-
-    def as_dict(self) -> dict:
-        return dataclasses.asdict(self)
 
 
 @dataclasses.dataclass
