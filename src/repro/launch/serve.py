@@ -168,7 +168,8 @@ def step_report(batcher, finished) -> list:
     submission, from the requests' wall stamps) p50/p95, the prompt tokens
     chunked prefill pushed through in a step (p95), and the mean work of a
     step: admissions and prefill chunks, and for a step that ran the
-    device its table width (paged) and the tokens it emitted."""
+    device its table width and the pages its attention read, as a share of
+    slots x width (paged), and the tokens it emitted."""
     steps = [h for h in batcher.health if h.active]
     if not steps:
         return []
@@ -195,11 +196,14 @@ def step_report(batcher, finished) -> list:
     mean = {f: float(np.mean([getattr(h, f) for h in batcher.health]))
             for f in ("admitted", "prefill_chunks")}
     mean.update({f: float(np.mean([getattr(h, f) for h in steps]))
-                 for f in ("width", "emitted")})
+                 for f in ("width", "emitted", "attn_pages")})
+    grid = batcher.B * mean["width"]
     lines.append(f"  per step: {mean['admitted']:.2f} admitted, "
                  f"{mean['prefill_chunks']:.2f} prefill chunks; per device "
                  f"step: {mean['emitted']:.2f} tokens emitted"
-                 + (f", table width {mean['width']:.1f} pages"
+                 + (f", table width {mean['width']:.1f} pages, attention "
+                    f"reads {mean['attn_pages']:.1f} pages "
+                    f"({mean['attn_pages'] / grid:.3f} of slots x width)"
                     if batcher.paged else ""))
     return lines
 

@@ -752,6 +752,11 @@ class ContinuousBatcher:
         return min(_next_pow2(self.pool.pages_for(deepest)),
                    self._table_width)
 
+    def _attn_pages(self, lengths: np.ndarray) -> int:
+        """Pages the step's paged attention reads: each live slot's
+        ceil(length / page_size)."""
+        return int((-(-lengths // self.page_size)).sum())
+
     # ------------------------------------------------------------------
     # the step
     # ------------------------------------------------------------------
@@ -866,9 +871,11 @@ class ContinuousBatcher:
                     if not s.free:
                         self.pool.set_length(i, s.pos + 1)
                 health.width = self._active_width()
+                lengths = self.pool.lengths(self.B)
+                health.attn_pages = self._attn_pages(lengths)
                 args += (jnp.asarray(self.pool.page_table(self.B,
                                                           health.width)),
-                         jnp.asarray(self.pool.lengths(self.B)))
+                         jnp.asarray(lengths))
         with _Phase(health, "dispatch"):
             (logits, self.cache), health.retries = self._device_step(args,
                                                                      fail)
@@ -1028,6 +1035,7 @@ class ContinuousBatcher:
             # pages render as the dump page — pad-row writes land there
             # harmlessly)
             health.width = _next_pow2(self.pool.pages_for(deepest + S))
+            health.attn_pages = self._attn_pages(lengths)
             args = (self.params, jnp.asarray(tokens), self.cache,
                     jnp.asarray(index),
                     jnp.asarray(self.pool.page_table(self.B, health.width)),

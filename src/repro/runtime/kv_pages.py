@@ -379,8 +379,8 @@ class PagePool:
     def page_table(self, n_slots: int, width: int) -> np.ndarray:
         """(n_slots, width) int32 table; unreserved entries point at the
         dump page, so every entry is a valid physical page id (the decode
-        kernel's BlockSpec DMAs the steered page unconditionally and relies
-        on the length mask, never on table validity)."""
+        kernel reads only each slot's live pages; the XLA gather path reads
+        every entry and relies on the length mask)."""
         table = np.full((n_slots, width), DUMP_PAGE, np.int32)
         for slot, pages in self._owned.items():
             if 0 <= slot < n_slots:

@@ -166,6 +166,7 @@ class StepHealth:
     prefill_chunks: int = 0    # chunked prefill launches in the step
     prefill_tokens: int = 0    # prompt tokens those launches carried
     width: int = 0             # decode page-table width (paged), in pages
+    attn_pages: int = 0        # pages the decode attention reads: sum ceil(len/ps)
     emitted: int = 0           # answer tokens the step produced
     pages_free: Optional[int] = None
     retries: int = 0

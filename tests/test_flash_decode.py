@@ -35,6 +35,18 @@ def _paged_setup(rng, B, Hkv, d, ps, W, lengths, P=None):
         (3, 8, 2, 32, 8, 4, (1, 17, 32)),      # GQA groups=4
         (4, 6, 3, 8, 4, 3, (12, 0, 3, 7)),     # free slot + odd heads
         (1, 2, 1, 64, 16, 1, (16,)),           # single page
+        # qwen2-0.5b's heads and pages (groups 7; 16 pages a block): ends
+        # on a block edge, one past it, mid-page, on a page edge, a slot at
+        # the full width, free slots between live ones
+        (6, 14, 2, 64, 16, 192, (256, 0, 257, 3072, 0, 13)),
+        (4, 14, 2, 64, 16, 192, (255, 16, 0, 512)),
+        # 21 pages a block: W = 192 is not a multiple of it (the last block
+        # has 3 pages)
+        (5, 21, 3, 16, 8, 192, (1536, 168, 0, 169, 7)),
+        # W below the block: 2 and 4 pages, groups 4
+        (3, 8, 2, 32, 8, 2, (0, 16, 9)),
+        (3, 8, 2, 32, 8, 4, (32, 0, 17)),
+        (2, 4, 1, 16, 4, 4, (0, 0)),           # every slot free
     ],
 )
 def test_kernel_matches_oracle(B, H, Hkv, d, ps, W, lengths):
@@ -74,6 +86,66 @@ def test_kernel_scaled_pages_match_oracle():
     ref = paged_decode_ref(q, kp, vp, table, lns, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "B,H,Hkv,d,ps,W,lengths",
+    [
+        (4, 14, 2, 64, 16, 24, (256, 0, 384, 7)),   # block edge, free, mid
+        (3, 8, 2, 16, 8, 4, (32, 0, 9)),            # W below the block
+    ],
+)
+def test_kernel_scaled_pages_across_blocks(B, H, Hkv, d, ps, W, lengths):
+    """int8 pages with their scale pages over several blocks a slot and
+    free slots between live ones."""
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
+    P = B * W + 1
+    kp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
+    vp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
+    _, _, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, lengths, P)
+    out = mx_flash_decode(q, kp, vp, table, lns, k_scale=ks, v_scale=vs,
+                          interpret=True)
+    ref = paged_decode_ref(q, kp, vp, table, lns, k_scale=ks, v_scale=vs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+    for i, ln in enumerate(lengths):
+        if ln == 0:
+            assert np.all(np.asarray(out[i]) == 0.0)
+
+
+@pytest.mark.parametrize("lengths,W", [
+    ((256, 0, 257, 3072, 0, 13), 192),   # every operand live somewhere
+    ((3072, 17, 0, 300, 1, 0, 2048, 5), 192),
+    ((0, 0, 0), 8),                      # no live slot: one idle step
+])
+def test_schedule_copies_each_live_page_once(lengths, W):
+    """The walk's fetch table, played through a pipeline that copies a
+    page operand only when its index changes: every live page of every
+    slot is copied exactly once, and no other page is."""
+    from repro.kernels.mx_flash_decode import _schedule
+
+    B, ps, ppb = len(lengths), 16, 16
+    nb = -(-W // ppb)
+    table = np.arange(B * W, dtype=np.int32).reshape(B, W) + 1
+    steps, slot, blk, npg, fetch = (np.asarray(a) for a in _schedule(
+        jnp.asarray(table), jnp.asarray(lengths, jnp.int32), ps, ppb, nb))
+    want = sorted(int(table[i, j]) for i, n in enumerate(lengths)
+                  for j in range(-(-n // ps)))
+    assert int(steps) == max(sum(-(-n // (ps * ppb)) for n in lengths), 1)
+    assert [(int(a), int(b)) for a, b in zip(slot[:steps], blk[:steps])
+            if lengths[a]] == [(i, b) for i, n in enumerate(lengths)
+                                for b in range(-(-n // (ps * ppb)))]
+    fetch = fetch.reshape(-1, ppb)[:steps]
+    copied = [int(fetch[0, p]) for p in range(ppb)]
+    copied += [int(fetch[t, p]) for t in range(1, steps) for p in range(ppb)
+               if fetch[t, p] != fetch[t - 1, p]]
+    if want:
+        assert sorted(copied) == want
+    else:
+        assert len(set(copied)) == 1   # the idle step's one operand page
 
 
 def test_stale_page_contents_are_dead():
@@ -302,6 +374,10 @@ def _verify_oracle(q, kp, vp, table, lns, *, k_scale=None, v_scale=None):
         (3, 8, 2, 32, 8, 4, 5, (5, 13, 32)),   # GQA groups=4, S > min length? no: 5<=5
         (4, 6, 3, 8, 4, 4, 5, (12, 0, 5, 16)), # free slot + S > page_size
         (1, 2, 1, 16, 16, 2, 2, (18,)),        # window crosses page boundary
+        # qwen2-0.5b's heads and pages: a one-row and a three-row window,
+        # across a block edge and at the full width, free slots between
+        (4, 14, 2, 64, 16, 24, 1, (257, 0, 384, 5)),
+        (4, 14, 2, 64, 16, 24, 3, (0, 258, 384, 3)),
     ],
 )
 def test_verify_kernel_matches_prefill_oracle(B, H, Hkv, d, ps, W, S, lengths):

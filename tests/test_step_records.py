@@ -107,11 +107,44 @@ def test_operator_report_reads_the_records(model_and_params):
         in lines[2]
     steps = [h for h in b.health if h.active]
     tokens = sum(len(r.output) for r in b.finished.values())
+    width = np.mean([h.width for h in steps])
+    pages = np.mean([h.attn_pages for h in steps])
     assert lines[3] == (
         f"  per step: {5 / len(b.health):.2f} admitted, "
         f"{b.prefill_launches / len(b.health):.2f} prefill chunks; "
         f"per device step: {tokens / len(steps):.2f} tokens emitted, "
-        f"table width {np.mean([h.width for h in steps]):.1f} pages")
+        f"table width {width:.1f} pages, attention reads {pages:.1f} pages "
+        f"({pages / (b.B * width):.3f} of slots x width)")
+
+
+@pytest.mark.parametrize("kw", [
+    dict(paged=True, page_size=4, prefill_chunk=4),
+    dict(paged=True, page_size=4, prefill_chunk=4, speculate=2),
+], ids=["paged-chunked", "speculative"])
+def test_attn_pages_counts_the_live_pages_of_each_step(model_and_params, kw):
+    """`StepHealth.attn_pages` is the sum of ceil(length / page_size) over
+    the lengths the step's program was given, and never more than the grid
+    of slots x table width."""
+    cfg, model, params = model_and_params
+    b = ContinuousBatcher(model, params, batch_slots=2, max_len=32, **kw)
+    given = []
+    run = b._device_step
+
+    def spy(args, fail_first, fn=None):
+        given.append(np.asarray(args[-1]))
+        return run(args, fail_first, fn=fn)
+
+    b._device_step = spy
+    rng = np.random.default_rng(3)
+    for i in range(5):
+        b.submit(Request(rid=i, prompt=rng.integers(
+            0, cfg.vocab, 3 + 5 * (i % 3)).astype(np.int32), max_new=3))
+    b.run_to_completion()
+    steps = [h for h in b.health if h.active]
+    assert len(steps) == len(given) > 0
+    for h, lengths in zip(steps, given):
+        assert h.attn_pages == sum(-(-int(n) // 4) for n in lengths), h
+        assert 0 < h.attn_pages <= b.B * h.width
 
 
 def test_phases_are_profiler_spans(model_and_params, tmp_path):

@@ -1,5 +1,6 @@
 """The main path's Pallas kernels compile for a TPU v5e at llama3.2-1b's
-published widths, at the plans the dispatch picks itself.
+published widths, at the plans the dispatch picks itself, and the decode
+attention and whole decode step at qwen2-0.5b's chat-cell shapes.
 
 Nothing here runs: each test lowers and compiles for a *described* chip
 (`jax.experimental.topologies`), which raises what the chip's compiler
@@ -151,6 +152,60 @@ def test_int8_paged_decode_compiles(one_chip):
     def fn(q, k, v, t, n, ks, vs):
         return mx_flash_decode(q, k, v, t, n, k_scale=ks, v_scale=vs)
     assert _compile(fn, q, kv, kv, i32, ln, sc, sc) == {"mx_flash_decode": 1}
+
+
+QWEN = get_config("qwen2-0.5b")
+QWEN_PAGES = 12289  # the chat cell's pool and its dump page
+
+
+@pytest.mark.parametrize("W", [128, 192])
+def test_qwen_decode_attention_compiles(one_chip, W):
+    """qwen2-0.5b's decode attention at the chat cell's shapes: 64 slots,
+    page 16, Hkv 2, 12,289 pages, at both table widths the cell reaches."""
+    B = 64
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q = sds((B, QWEN.n_heads, QWEN.hd))
+    kv = sds((QWEN_PAGES, PAGE, QWEN.n_kv_heads, QWEN.hd))
+    census = _compile(lambda *a: mx_flash_decode(*a), q, kv, kv,
+                      sds((B, W), jnp.int32), sds((B,), jnp.int32))
+    assert census == {"mx_flash_decode": 1}
+
+
+def test_qwen_decode_step_reads_the_pools_in_place(one_chip, pol):
+    """The whole qwen2-0.5b decode step, as the chat cell runs it (f32
+    pools of 12,289 pages, 64 slots, W 128): one decode attention launch,
+    and no more pool-sized copies than the layer body's four (each of K
+    and V sliced out of the layer stack and put back), so the kernel takes
+    its pools as they lie."""
+    import re
+
+    from repro.models import build_model
+
+    model = build_model(QWEN)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    B, W = 64, 128
+    i32 = jnp.int32
+    with ops.use_policy(pol):
+        params = jax.tree.map(sds, model.abstract())
+        cache = jax.tree.map(sds, model.make_paged_cache(
+            QWEN_PAGES, PAGE, mode="abstract", dtype=jnp.float32))
+        text = jax.jit(model.decode_step_paged).lower(
+            params, sds(jax.ShapeDtypeStruct((B, 1), i32)), cache,
+            sds(jax.ShapeDtypeStruct((B,), i32)),
+            sds(jax.ShapeDtypeStruct((B, W), i32)),
+            sds(jax.ShapeDtypeStruct((B,), i32))).compile().as_text()
+    assert kernel_launches(text)["mx_flash_decode"] == 1
+    pool = f"{QWEN_PAGES},{PAGE},{QWEN.n_kv_heads},{QWEN.hd}]"
+    copies = [line for line in text.splitlines()
+              if re.search(r"= \S+ copy\(", line)
+              and pool in line.split(" copy(")[0]]
+    assert 0 < len(copies) <= 4, copies
 
 
 def test_grouped_matmul_compiles(one_chip, pol):
