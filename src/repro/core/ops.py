@@ -470,7 +470,8 @@ def per_device(fn, *args, specs=None):
     in_specs, out_specs = spec if spec else ((P(),) * len(args), P())
 
     def local(*shards):
-        with collective_policy(coll.mesh, axis=coll.axis, enabled=False):
+        with jax.named_scope("tp.per_device"), collective_policy(
+                coll.mesh, axis=coll.axis, enabled=False):
             return fn(*shards)
 
     return jax.shard_map(local, mesh=coll.mesh, in_specs=tuple(in_specs),
@@ -743,10 +744,13 @@ def _ring_caller(mesh, ax, P_, direction, cc, ep, tp_mode,
                   bias=b_s, residual=r_s, out_dtype=out_dtype,
                   direction=direction, a_scale=a_sc, b_scale=b_sc,
                   fault=fault)
-        if tp_mode == "allgather":
-            return ring_allgather_matmul(x_s, w_s, b_gate=g_s,
-                                         bg_scale=bg_sc, **kw)
-        return ring_matmul_reduce_scatter(x_s, w_s, **kw)
+        # "tp.allgather" / "tp.reduce_scatter": a profile names the ring's
+        # device ops (chunk GEMMs, hops) by it
+        with jax.named_scope(f"tp.{tp_mode}"):
+            if tp_mode == "allgather":
+                return ring_allgather_matmul(x_s, w_s, b_gate=g_s,
+                                             bg_scale=bg_sc, **kw)
+            return ring_matmul_reduce_scatter(x_s, w_s, **kw)
 
     out_specs = (out_spec, P()) if cc.abft is not None else out_spec
     return jax.jit(jax.shard_map(

@@ -31,7 +31,8 @@ from ..launch.mesh import make_mesh
 from ..launch.steps import make_chunked_prefill_step, make_serve_step
 from ..models import build_model
 from ..parallel.sharding import (
-    collective_policy, make_rules, tree_shardings, use_rules,
+    collective_policy, current_collectives, make_rules, tree_shardings,
+    use_rules,
 )
 
 
@@ -236,6 +237,13 @@ def _run_continuous(model, cfg, params, args, rules) -> int:
         print(f"  pages: {st.pages_in_use} in use / {st.num_pages} pool "
               f"(high water {st.high_water}, page_size {st.page_size}, "
               f"peak utilization {st.high_water / st.num_pages:.2f})")
+        coll = current_collectives()
+        if coll is not None:
+            # per projection kind, ring against fallback: a silent
+            # fallback to replicated operands shows here
+            print("  tp routes: " + ", ".join(
+                f"{mode} {path} {n}"
+                for (mode, path), n in sorted(coll.routed.items())))
     if args.speculate:
         sp = batcher.spec_stats()
         print(f"  speculation [k={args.speculate}, draft {args.draft}]: "
