@@ -89,37 +89,49 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, scale=None):
     return (p @ v.astype(jnp.float32)).astype(q.dtype)
 
 
-def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, *,
+def _gather_pages(k_pages, v_pages, layer, page_table, d, k_scale, v_scale):
+    """Each slot's (W * ps) logical KV rows of layer ``layer``, f32 and
+    dequantized, as (B, W * ps, Hkv, d): one gather per operand from the
+    stacked pool at (layer, page) — no layer of the pool is sliced out —
+    and only the gathered rows are reshaped into heads."""
+    B, W = page_table.shape
+    ps, lanes = k_pages.shape[2:]
+    Hkv = lanes // d
+    k = k_pages[layer, page_table].astype(jnp.float32)  # (B, W, ps, Hkv*d)
+    v = v_pages[layer, page_table].astype(jnp.float32)
+    k = k.reshape(B, W * ps, Hkv, d)
+    v = v.reshape(B, W * ps, Hkv, d)
+    if k_scale is not None:
+        k = k * k_scale[layer, page_table].reshape(B, W * ps, Hkv, 1)
+        v = v * v_scale[layer, page_table].reshape(B, W * ps, Hkv, 1)
+    return k, v
+
+
+def paged_decode_ref(q, k_pages, v_pages, layer, page_table, lengths, *,
                      k_scale=None, v_scale=None):
     """Gather-based oracle for `mx_flash_decode` — and the XLA fallback the
     model stack runs off-TPU.
 
-    q: (B, H, d) one token per slot; k_pages / v_pages: (P, ps, Hkv, d)
-    flat page pools; page_table: (B, W) physical page ids; lengths: (B,)
-    live token counts (0 = free slot -> zero output row).  Optional
-    k_scale / v_scale: (P, ps, Hkv) per-row dequant sidecars (int8 cache).
+    q: (B, H, d) one token per slot; k_pages / v_pages: (L, P, ps, Hkv * d)
+    layer-stacked page pools, read at ``layer``; page_table: (B, W)
+    physical page ids; lengths: (B,) live token counts (0 = free slot ->
+    zero output row).  Optional k_scale / v_scale: (L, P, ps, Hkv) per-row
+    dequant sidecars (int8 cache).
 
     The gather materializes each slot's logical (W*ps) KV prefix — exactly
     the padded-cache traffic the paged kernel's steered page DMAs avoid.
     """
     B, H, d = q.shape
-    _, ps, Hkv, _ = k_pages.shape
+    k, v = _gather_pages(k_pages, v_pages, layer, page_table, d,
+                         k_scale, v_scale)
+    Hkv, span = k.shape[2], k.shape[1]
     G = H // Hkv
-    W = page_table.shape[1]
     lengths = lengths.astype(jnp.int32)
-
-    k = k_pages[page_table].astype(jnp.float32)  # (B, W, ps, Hkv, d)
-    v = v_pages[page_table].astype(jnp.float32)
-    if k_scale is not None:
-        k = k * k_scale[page_table][..., None]
-        v = v * v_scale[page_table][..., None]
-    k = k.reshape(B, W * ps, Hkv, d)
-    v = v.reshape(B, W * ps, Hkv, d)
 
     qh = q.astype(jnp.float32).reshape(B, Hkv, G, d)
     s = jnp.einsum("bhgd,bshd->bhgs", qh, k, precision=_F32,
                    preferred_element_type=jnp.float32) / (d ** 0.5)
-    kpos = jnp.arange(W * ps)[None, None, None, :]
+    kpos = jnp.arange(span)[None, None, None, :]
     # free slots (length 0) attend to position 0 so the softmax stays
     # defined; their rows are zeroed below (matching the kernel's output)
     keep = kpos < jnp.maximum(lengths, 1)[:, None, None, None]
@@ -131,20 +143,20 @@ def paged_decode_ref(q, k_pages, v_pages, page_table, lengths, *,
     return o.reshape(B, H, d).astype(q.dtype)
 
 
-def paged_prefill_ref(q, k_pages, v_pages, page_table, index, *,
+def paged_prefill_ref(q, k_pages, v_pages, layer, page_table, index, *,
                       k_scale=None, v_scale=None):
     """Multi-query sibling of `paged_decode_ref`: causal attention of S
     query tokens at positions [index, index+S) over a paged KV cache whose
     pages already hold every position <= the query's own (the chunked
     prefill-into-pages path writes the chunk's K/V rows BEFORE attending).
 
-    q: (B, S, H, d); k_pages / v_pages: (P, ps, Hkv, d) flat page pools;
-    page_table: (B, W) physical page ids; index: (B,) each slot's chunk
-    start position.  Optional k_scale / v_scale: (P, ps, Hkv) per-row
-    dequant sidecars (int8 cache).  Query j of slot b attends over cached
-    positions kpos <= index[b] + j — the causal mask doubles as the length
-    mask, so stale rows past the chunk (recycled pages) are dead by
-    construction.
+    q: (B, S, H, d); k_pages / v_pages: (L, P, ps, Hkv * d) layer-stacked
+    page pools, read at ``layer``; page_table: (B, W) physical page ids;
+    index: (B,) each slot's chunk start position.  Optional k_scale /
+    v_scale: (L, P, ps, Hkv) per-row dequant sidecars (int8 cache).  Query
+    j of slot b attends over cached positions kpos <= index[b] + j — the
+    causal mask doubles as the length mask, so stale rows past the chunk
+    (recycled pages) are dead by construction.
 
     Gather-based like the decode oracle: materializes each slot's logical
     (W*ps) KV span once per chunk, which is exactly the prefill traffic a
@@ -152,23 +164,16 @@ def paged_prefill_ref(q, k_pages, v_pages, page_table, index, *,
     still rides the MX dispatch in the caller.
     """
     B, S, H, d = q.shape
-    _, ps, Hkv, _ = k_pages.shape
+    k, v = _gather_pages(k_pages, v_pages, layer, page_table, d,
+                         k_scale, v_scale)
+    Hkv, span = k.shape[2], k.shape[1]
     G = H // Hkv
-    W = page_table.shape[1]
-
-    k = k_pages[page_table].astype(jnp.float32)  # (B, W, ps, Hkv, d)
-    v = v_pages[page_table].astype(jnp.float32)
-    if k_scale is not None:
-        k = k * k_scale[page_table][..., None]
-        v = v * v_scale[page_table][..., None]
-    k = k.reshape(B, W * ps, Hkv, d)
-    v = v.reshape(B, W * ps, Hkv, d)
 
     qh = q.astype(jnp.float32).reshape(B, S, Hkv, G, d)
     s = jnp.einsum("bqhgd,bshd->bhgqs", qh, k, precision=_F32,
                    preferred_element_type=jnp.float32) / (d ** 0.5)
     qpos = jnp.asarray(index)[:, None] + jnp.arange(S)  # (B, S)
-    kpos = jnp.arange(W * ps)
+    kpos = jnp.arange(span)
     keep = kpos[None, None, :] <= qpos[:, :, None]      # (B, S, W*ps)
     s = jnp.where(keep[:, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)

@@ -68,22 +68,22 @@ def gemm_vmem_bytes(
 
 
 def paged_attention_vmem_bytes(
-    rows: int, cols: int, d: int, ppb: int, *,
-    kv_bytes: int, q_bytes: int, scales: bool = False,
+    rows: int, ps: int, lanes: int, ppb: int, *,
+    kv_bytes: int, q_bytes: int, scale_rows: int = 0,
 ) -> int:
     """VMEM one paged-attention launch claims (`kernels/mx_flash_decode`)
-    with ``rows`` query rows a slot, ``cols`` pool rows a page and ``ppb``
-    pages a block: the double-buffered page, query, output, label (and
-    scale) blocks, the (m, l, acc) scratch, and the body's f32 values —
-    each page cast, the block's K and V, the scores, probabilities and
-    mask."""
-    blk = ppb * cols
-    piped = 2 * ppb * buffer_bytes(cols, d, kv_bytes)
-    piped += 2 * buffer_bytes(rows, d, q_bytes) + buffer_bytes(rows, blk, 4)
-    if scales:
-        piped += buffer_bytes(2, blk, 4)
-    scratch = 2 * buffer_bytes(rows, 1, 4) + buffer_bytes(rows, d, 4)
-    temps = 4 * buffer_bytes(blk, d, 4) + 3 * buffer_bytes(rows, blk, 4)
+    with ``rows`` query rows a slot, pages of ``ps`` pool rows ``lanes``
+    wide and ``ppb`` pages a block: the double-buffered page, query, output,
+    label (and ``scale_rows`` scale) blocks, the (m, l, acc) scratch, and
+    the body's f32 values — each page cast, the block's K and V, the
+    scores, probabilities and mask."""
+    blk = ppb * ps
+    piped = 2 * ppb * buffer_bytes(ps, lanes, kv_bytes)
+    piped += 2 * buffer_bytes(rows, lanes, q_bytes) + buffer_bytes(rows, blk, 4)
+    if scale_rows:
+        piped += buffer_bytes(scale_rows, blk, 4)
+    scratch = 2 * buffer_bytes(rows, 1, 4) + buffer_bytes(rows, lanes, 4)
+    temps = 4 * buffer_bytes(blk, lanes, 4) + 3 * buffer_bytes(rows, blk, 4)
     return 2 * piped + scratch + temps
 
 

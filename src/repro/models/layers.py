@@ -119,28 +119,30 @@ def _repeat_kv(k: jax.Array, groups: int) -> jax.Array:
     )
 
 
-def _paged_kernel(kernel, q, cache, page_table, lengths, interpret):
-    """``kernel`` (`mx_flash_decode` / `mx_flash_verify`) over the cache's
-    page pools.  On a tensor-parallel mesh every device attends with its
-    own heads: query heads (axis -2 of q) and the pools' kv heads shard
-    together over the policy axis, so no device gathers another's pages."""
+def _paged_kernel(kernel, q, cache, layer, page_table, lengths, interpret):
+    """``kernel`` (`mx_flash_decode` / `mx_flash_verify`) over layer
+    ``layer`` of the cache's stacked page pools.  On a tensor-parallel mesh
+    every device attends with its own heads: query heads (axis -2 of q) and
+    the pools' kv-head lanes shard together over the policy axis, so no
+    device gathers another's pages."""
     from jax.sharding import PartitionSpec as P
 
     scales = [cache[k] for k in ("k_scale", "v_scale") if k in cache]
 
-    def call(q, k_pages, v_pages, table, lens, *sc):
+    def call(q, k_pages, v_pages, lay, table, lens, *sc):
         k_scale, v_scale = sc if sc else (None, None)
-        return kernel(q, k_pages, v_pages, table, lens, k_scale=k_scale,
+        return kernel(q, k_pages, v_pages, lay, table, lens, k_scale=k_scale,
                       v_scale=v_scale, interpret=interpret)
 
     def heads(ax, size):
-        if cache["k_pages"].shape[2] % size:
+        if (cache["k_pages"].shape[-1] // q.shape[-1]) % size:
             return None
-        qs, pages = P(*[None] * (q.ndim - 2), ax, None), P(None, None, ax, None)
-        return ((qs, pages, pages, P(), P(), *[P(None, None, ax)] * len(scales)),
+        qs = P(*[None] * (q.ndim - 2), ax, None)
+        lanes = P(None, None, None, ax)
+        return ((qs, lanes, lanes, P(), P(), P(), *[lanes] * len(scales)),
                 qs)
 
-    return ops.per_device(call, q, cache["k_pages"], cache["v_pages"],
+    return ops.per_device(call, q, cache["k_pages"], cache["v_pages"], layer,
                           page_table, lengths, *scales, specs=heads)
 
 
@@ -388,75 +390,86 @@ class Attention(Module):
 
     # ---------------- paged KV-cache decode path ----------------
 
-    def _write_kv_pages(self, cache, page_ids, offs, k_new, v_new):
-        """Scatter K/V rows into the page pools at (page_ids, offs); the
-        single write path shared by decode (one token per slot) and
-        chunked prefill (a chunk per slot — the leading dims of page_ids/
-        offs/k_new/v_new just broadcast).  A quantized cache (pytree
-        self-describes via its "k_scale" key) quantizes on write with a
-        per-(row, head) scale."""
+    def _write_kv_pages(self, cache, layer, page_ids, offs, k_new, v_new):
+        """Scatter K/V rows into layer ``layer`` of the stacked page pools
+        at (page_ids, offs), where the pools lie; the single write path
+        shared by decode (one token per slot) and chunked prefill (a chunk
+        per slot — the leading dims of page_ids/offs/k_new/v_new just
+        broadcast).  k_new / v_new: (..., Hkv, hd), stored as (..., Hkv *
+        hd) rows.  A quantized cache (pytree self-describes via its
+        "k_scale" key) quantizes on write with a per-(row, head) scale."""
         cache = dict(cache)
+        at = (layer, page_ids, offs)
+
+        def as_rows(x):  # (..., Hkv, hd) -> (..., Hkv * hd)
+            return x.reshape(*x.shape[:-2], -1)
+
         if "k_scale" in cache:
             names = {"int8": "int8", "float8_e4m3fn": "fp8_e4m3"}
             spec = QuantSpec(names[str(cache["k_pages"].dtype)], "tile")
             qk, ks = quantize(k_new, spec, axis=-1)
             qv, vs = quantize(v_new, spec, axis=-1)
-            cache["k_pages"] = cache["k_pages"].at[page_ids, offs].set(qk)
-            cache["v_pages"] = cache["v_pages"].at[page_ids, offs].set(qv)
-            cache["k_scale"] = cache["k_scale"].at[page_ids, offs].set(
-                ks[..., 0])
-            cache["v_scale"] = cache["v_scale"].at[page_ids, offs].set(
-                vs[..., 0])
+            cache["k_pages"] = cache["k_pages"].at[at].set(as_rows(qk))
+            cache["v_pages"] = cache["v_pages"].at[at].set(as_rows(qv))
+            cache["k_scale"] = cache["k_scale"].at[at].set(ks[..., 0])
+            cache["v_scale"] = cache["v_scale"].at[at].set(vs[..., 0])
         else:
             dt = cache["k_pages"].dtype
-            cache["k_pages"] = cache["k_pages"].at[page_ids, offs].set(
-                k_new.astype(dt))
-            cache["v_pages"] = cache["v_pages"].at[page_ids, offs].set(
-                v_new.astype(dt))
+            cache["k_pages"] = cache["k_pages"].at[at].set(
+                as_rows(k_new).astype(dt))
+            cache["v_pages"] = cache["v_pages"].at[at].set(
+                as_rows(v_new).astype(dt))
         return cache
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         dtype=jnp.bfloat16, kv_quant: Optional[QuantSpec] = None):
-        """Flat page-pool cache: (num_pages, page_size, Hkv, hd) per
-        operand.  `kv_quant` (a quantized core.precision.QuantSpec, e.g.
-        QuantSpec("int8")) stores narrow payloads plus per-row f32 scale
-        pages; the cache pytree self-describes via its `k_scale` key."""
-        hd = self.hd
-        shape = (num_pages, page_size, self.n_kv_heads, hd)
+                         dtype=jnp.bfloat16, kv_quant: Optional[QuantSpec] = None,
+                         layers: int = 1):
+        """Layer-stacked page pools: (layers, num_pages, page_size, Hkv *
+        hd) per operand, one token's kv heads side by side in a row — the
+        layout the decode kernel reads and the writes scatter into, lane-
+        dense at the served widths.  `kv_quant` (a quantized
+        core.precision.QuantSpec, e.g. QuantSpec("int8")) stores narrow
+        payloads plus per-(row, head) f32 scale pools (layers, num_pages,
+        page_size, Hkv); the cache pytree self-describes via its `k_scale`
+        key."""
+        shape = (layers, num_pages, page_size, self.n_kv_heads * self.hd)
         if kv_quant is not None and kv_quant.quantized:
-            cache = {
+            scales = shape[:3] + (self.n_kv_heads,)
+            return {
                 "k_pages": jnp.zeros(shape, kv_quant.jnp_dtype),
                 "v_pages": jnp.zeros(shape, kv_quant.jnp_dtype),
-                "k_scale": jnp.ones(shape[:3], jnp.float32),
-                "v_scale": jnp.ones(shape[:3], jnp.float32),
+                "k_scale": jnp.ones(scales, jnp.float32),
+                "v_scale": jnp.ones(scales, jnp.float32),
             }
-            return cache
         return {"k_pages": jnp.zeros(shape, dtype),
                 "v_pages": jnp.zeros(shape, dtype)}
 
     def abstract_paged_cache(self, num_pages: int, page_size: int,
                              dtype=jnp.bfloat16,
-                             kv_quant: Optional[QuantSpec] = None):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self.init_paged_cache(num_pages, page_size, dtype, kv_quant),
-        )
+                             kv_quant: Optional[QuantSpec] = None,
+                             layers: int = 1):
+        return jax.eval_shape(lambda: self.init_paged_cache(
+            num_pages, page_size, dtype, kv_quant, layers))
 
     def paged_cache_axes(self, kv_quant: Optional[QuantSpec] = None):
-        ax = ("pages", "page_size", "kv_heads", "head_dim")
+        # a row is the token's kv heads side by side: it shards on the
+        # model axis by the "kv_heads" rule, whole heads to a device
+        ax = ("layers", "pages", "page_size", "kv_heads")
         axes = {"k_pages": ax, "v_pages": ax}
         if kv_quant is not None and kv_quant.quantized:
-            axes["k_scale"] = ax[:3]
-            axes["v_scale"] = ax[:3]
+            axes["k_scale"] = ax
+            axes["v_scale"] = ax
         return axes
 
-    def decode_paged(self, p, x, cache, index, page_table, lengths, *,
-                     residual=None):
-        """One decode step against a paged KV cache.  x: (B, 1, D);
-        cache: page pools from `init_paged_cache`; index: (B,) per-slot
-        positions; page_table: (B, W) physical page ids (runtime/kv_pages —
-        free slots' rows point at the dump page, so the batched write needs
-        no masking); lengths: (B,) live token counts (index+1 for active
+    def decode_paged(self, p, x, cache, layer, index, page_table, lengths,
+                     *, residual=None):
+        """One decode step of layer ``layer`` against the layer-stacked
+        paged KV cache.  x: (B, 1, D); cache: page pools from
+        `init_paged_cache`, written and read where they lie (the model's
+        layer scan carries them); index: (B,) per-slot positions;
+        page_table: (B, W) physical page ids (runtime/kv_pages — free
+        slots' rows point at the dump page, so the batched write needs no
+        masking); lengths: (B,) live token counts (index+1 for active
         slots, 0 for free ones).
 
         The attention itself dispatches like every other MX op: the Pallas
@@ -464,22 +477,22 @@ class Attention(Module):
         gather-based jnp oracle (`paged_decode_ref`) as the XLA fallback.
         """
         b = x.shape[0]
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[2]
         idx_b = jnp.broadcast_to(jnp.asarray(index), (b,))
         positions = idx_b[:, None]
         q, k_new, v_new = self._qkv(p, x, positions)
         rows = jnp.arange(b)
         page_ids = page_table[rows, idx_b // ps]
         offs = idx_b % ps
-        cache = self._write_kv_pages(cache, page_ids, offs,
+        cache = self._write_kv_pages(cache, layer, page_ids, offs,
                                      k_new[:, 0], v_new[:, 0])  # (B, Hkv, hd)
         policy = ops.current_policy()
         if policy.backend == "pallas_mx":
-            o = _paged_kernel(mx_flash_decode, q[:, 0], cache, page_table,
-                              lengths, policy.interpret)
+            o = _paged_kernel(mx_flash_decode, q[:, 0], cache, layer,
+                              page_table, lengths, policy.interpret)
         else:
             o = paged_decode_ref(q[:, 0], cache["k_pages"], cache["v_pages"],
-                                 page_table, lengths,
+                                 layer, page_table, lengths,
                                  k_scale=cache.get("k_scale"),
                                  v_scale=cache.get("v_scale"))
         o = o.reshape(b, 1, self.n_heads * self.hd)
@@ -489,28 +502,31 @@ class Attention(Module):
 
     # ---------------- chunked prefill (paged cache) ----------------
 
-    def prefill_paged(self, p, x, cache, index, page_table, *, residual=None):
-        """Chunked prefill writing K/V DIRECTLY into pages: x (B, S, D)
-        fills cache rows for positions [index, index+S) — S prompt tokens
-        per launch instead of S decode-interleaved steps — then attends
-        causally against the paged prefix (including pages mounted from the
-        prefix cache, which is what makes a shared system prompt cost zero
-        prefill GEMMs for the matched span).  index: (B,) per-slot chunk
-        start positions; page_table: (B, W) physical page ids covering at
-        least positions index+S-1.  Quantized caches ("k_scale" present)
-        quantize-on-write per row, exactly as `decode_paged` does."""
+    def prefill_paged(self, p, x, cache, layer, index, page_table, *,
+                      residual=None):
+        """Chunked prefill writing K/V DIRECTLY into layer ``layer``'s
+        pages: x (B, S, D) fills cache rows for positions [index, index+S)
+        — S prompt tokens per launch instead of S decode-interleaved steps
+        — then attends causally against the paged prefix (including pages
+        mounted from the prefix cache, which is what makes a shared system
+        prompt cost zero prefill GEMMs for the matched span).  index: (B,)
+        per-slot chunk start positions; page_table: (B, W) physical page
+        ids covering at least positions index+S-1.  Quantized caches
+        ("k_scale" present) quantize-on-write per row, exactly as
+        `decode_paged` does."""
         b, sq, _ = x.shape
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[2]
         idx_b = jnp.broadcast_to(jnp.asarray(index), (b,))
         positions = idx_b[:, None] + jnp.arange(sq)  # (B, S)
         q, k_new, v_new = self._qkv(p, x, positions)
         page_ids = jnp.take_along_axis(page_table, positions // ps, axis=1)
         offs = positions % ps
-        cache = self._write_kv_pages(cache, page_ids, offs, k_new, v_new)
+        cache = self._write_kv_pages(cache, layer, page_ids, offs, k_new,
+                                     v_new)
         # the attention is the gather oracle on every backend: the split-KV
         # Pallas kernel is single-query (decode); prefill chunks are
         # compute-bound in the qkv/out GEMMs, which already ride MX dispatch
-        o = paged_prefill_ref(q, cache["k_pages"], cache["v_pages"],
+        o = paged_prefill_ref(q, cache["k_pages"], cache["v_pages"], layer,
                               page_table, idx_b,
                               k_scale=cache.get("k_scale"),
                               v_scale=cache.get("v_scale"))
@@ -521,13 +537,13 @@ class Attention(Module):
 
     # ---------------- speculative verify (paged cache) ----------------
 
-    def verify_paged(self, p, x, cache, index, page_table, lengths, *,
+    def verify_paged(self, p, x, cache, layer, index, page_table, lengths, *,
                      residual=None):
         """Batched-verify step for speculative decoding: x (B, S, D) holds
         each slot's S = k+1 window tokens (the committed token plus k
         drafts).  K/V rows for positions [index, index+S) are written into
-        the pages first (quantize-on-write included, exactly like
-        `prefill_paged`), then all S rows attend in ONE launch through
+        layer ``layer``'s pages first (quantize-on-write included, exactly
+        like `prefill_paged`), then all S rows attend in ONE launch through
         `mx_flash_verify` — the decode kernel widened to an S-row query
         block, scoring the whole window for the price of one weight read.
 
@@ -536,25 +552,26 @@ class Attention(Module):
         ones — free slots' writes land on the dump page and their output
         rows are zero, the decode-path convention)."""
         b, sq, _ = x.shape
-        ps = cache["k_pages"].shape[1]
+        ps = cache["k_pages"].shape[2]
         idx_b = jnp.broadcast_to(jnp.asarray(index), (b,))
         positions = idx_b[:, None] + jnp.arange(sq)  # (B, S)
         q, k_new, v_new = self._qkv(p, x, positions)
         page_ids = jnp.take_along_axis(page_table, positions // ps, axis=1)
         offs = positions % ps
-        cache = self._write_kv_pages(cache, page_ids, offs, k_new, v_new)
-        kw = dict(
-            k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"))
+        cache = self._write_kv_pages(cache, layer, page_ids, offs, k_new,
+                                     v_new)
         policy = ops.current_policy()
         if policy.backend == "pallas_mx":
-            o = _paged_kernel(mx_flash_verify, q, cache, page_table, lengths,
-                              policy.interpret)
+            o = _paged_kernel(mx_flash_verify, q, cache, layer, page_table,
+                              lengths, policy.interpret)
         else:
             # the causal window mask of the prefill oracle IS the verify
             # mask (row r at position lengths-S+r); free slots (length 0)
             # produce NaN softmax rows there — zero them like the kernel
             o = paged_prefill_ref(q, cache["k_pages"], cache["v_pages"],
-                                  page_table, lengths - sq, **kw)
+                                  layer, page_table, lengths - sq,
+                                  k_scale=cache.get("k_scale"),
+                                  v_scale=cache.get("v_scale"))
             o = jnp.where((lengths > 0)[:, None, None, None], o, 0.0)
         o = o.reshape(b, sq, self.n_heads * self.hd)
         out = ops.linear(o, p["wo"], residual=residual, out_dtype=x.dtype,

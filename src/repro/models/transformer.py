@@ -155,50 +155,25 @@ class TransformerBlock(Module):
     # ---- paged decode ----
 
     def init_paged_cache(self, num_pages, page_size, dtype=jnp.bfloat16,
-                         kv_quant=None):
+                         kv_quant=None, layers=1):
         return self._attn().init_paged_cache(num_pages, page_size, dtype,
-                                             kv_quant)
+                                             kv_quant, layers)
 
     def abstract_paged_cache(self, num_pages, page_size, dtype=jnp.bfloat16,
-                             kv_quant=None):
+                             kv_quant=None, layers=1):
         return self._attn().abstract_paged_cache(num_pages, page_size, dtype,
-                                                 kv_quant)
+                                                 kv_quant, layers)
 
     def paged_cache_axes(self, kv_quant=None):
         return self._attn().paged_cache_axes(kv_quant)
 
-    def decode_paged(self, p, x, cache, index, page_table, lengths):
-        attn = self._attn()
-        x, cache = attn.decode_paged(p["attn"], rms_norm(x, p["ln1"]), cache,
-                                     index, page_table, lengths, residual=x)
-        ffn = self._ffn()
-        h = rms_norm(x, p["ln2"])
-        if self.use_moe:
-            y, _ = ffn(p["ffn"], h)
-            x = x + y
-        else:
-            x = ffn(p["ffn"], h, residual=x)
-        return x, cache
-
-    def prefill_paged(self, p, x, cache, index, page_table):
-        """Multi-token page-writing step (chunked prefill into pages)."""
-        attn = self._attn()
-        x, cache = attn.prefill_paged(p["attn"], rms_norm(x, p["ln1"]), cache,
-                                      index, page_table, residual=x)
-        ffn = self._ffn()
-        h = rms_norm(x, p["ln2"])
-        if self.use_moe:
-            y, _ = ffn(p["ffn"], h)
-            x = x + y
-        else:
-            x = ffn(p["ffn"], h, residual=x)
-        return x, cache
-
-    def verify_paged(self, p, x, cache, index, page_table, lengths):
-        """Speculative batched-verify step: S window tokens per slot."""
-        attn = self._attn()
-        x, cache = attn.verify_paged(p["attn"], rms_norm(x, p["ln1"]), cache,
-                                     index, page_table, lengths, residual=x)
+    def paged(self, step: str, p, x, cache, *args):
+        """One layer of a paged program: the attention's ``step``
+        (`Attention.decode_paged`, `prefill_paged` or `verify_paged`, which
+        writes the layer's K/V rows into the stacked pools) with the
+        residual fused, then the FFN."""
+        x, cache = getattr(self._attn(), step)(
+            p["attn"], rms_norm(x, p["ln1"]), cache, *args, residual=x)
         ffn = self._ffn()
         h = rms_norm(x, p["ln2"])
         if self.use_moe:
@@ -441,8 +416,10 @@ class DecoderLM(Module):
     def make_paged_cache(self, num_pages: int, page_size: int,
                          mode: str = "init", dtype=jnp.bfloat16,
                          kv_quant=None):
-        """Paged cache pytree: per segment, layer-stacked page pools
-        (n, num_pages, page_size, Hkv, hd).  The page table and lengths are
+        """Paged cache pytree: per segment, one layer-stacked pool per
+        operand, (n, num_pages, page_size, Hkv * hd).  The step carries the
+        pools through its layer scan and writes them where they lie, so a
+        donated step updates them in place.  The page table and lengths are
         NOT part of the cache — they are per-step scheduler outputs
         (runtime/kv_pages) shared by every layer."""
         if not self.supports_paged():
@@ -452,36 +429,43 @@ class DecoderLM(Module):
         for i, seg in enumerate(self.segments()):
             block = make_block(seg.kind, self.cfg)
             if mode == "axes":
-                one = block.paged_cache_axes(kv_quant)
-            elif mode == "abstract":
-                one = block.abstract_paged_cache(num_pages, page_size, dtype,
-                                                 kv_quant)
-            else:
-                one = block.init_paged_cache(num_pages, page_size, dtype,
-                                             kv_quant)
-            cache[f"seg{i}"] = self._stack_cache(one, seg.n, mode)
+                cache[f"seg{i}"] = block.paged_cache_axes(kv_quant)
+                continue
+            make = (block.abstract_paged_cache if mode == "abstract"
+                    else block.init_paged_cache)
+            cache[f"seg{i}"] = make(num_pages, page_size, dtype, kv_quant,
+                                    seg.n)
         return cache
+
+    def _scan_paged(self, step, p, tokens, cache, *args):
+        """The stack's paged pass: `TransformerBlock.paged` over ``step``
+        (an `Attention` paged method) a layer at a time.  The pools ride
+        the scan's carry, not its xs/ys: each layer writes its rows at its
+        own index of the stacked pools, and nothing slices a layer out or
+        stacks it back."""
+        x = self._embed_inputs(p, tokens)
+        new_cache = dict(cache)
+        for i, seg in enumerate(self.segments()):
+            block = make_block(seg.kind, self.cfg)
+
+            def body(carry, scanned, block=block):
+                h, pools = carry
+                layer_params, layer = scanned
+                return block.paged(step, layer_params, h, pools, layer,
+                                   *args), None
+
+            (x, new_cache[f"seg{i}"]), _ = jax.lax.scan(
+                body, (x, cache[f"seg{i}"]),
+                (p[f"seg{i}"], jnp.arange(seg.n, dtype=jnp.int32)))
+        return self._head(p, x), new_cache
 
     def decode_step_paged(self, p, token, cache, index, page_table, lengths):
         """One token for the whole stack against the paged KV cache.
         token: (B, 1); index: (B,) per-slot positions; page_table: (B, W)
         physical page ids; lengths: (B,) live token counts.
         Returns (logits, cache)."""
-        cfg = self.cfg
-        x = self._embed_inputs(p, token)
-        new_cache = dict(cache)
-        for i, seg in enumerate(self.segments()):
-            block = make_block(seg.kind, cfg)
-
-            def body(h, scanned):
-                layer_params, layer_cache = scanned
-                return block.decode_paged(layer_params, h, layer_cache,
-                                          index, page_table, lengths)
-
-            x, new_cache[f"seg{i}"] = jax.lax.scan(
-                body, x, (p[f"seg{i}"], cache[f"seg{i}"])
-            )
-        return self._head(p, x), new_cache
+        return self._scan_paged("decode_paged", p, token, cache, index,
+                                page_table, lengths)
 
     def prefill_step_paged(self, p, tokens, cache, index, page_table):
         """S prompt tokens through the whole stack in ONE step, written
@@ -493,21 +477,8 @@ class DecoderLM(Module):
         if not self.supports_paged():
             raise ValueError(f"{self.cfg.name}: paged prefill needs "
                              "attention-only segments")
-        cfg = self.cfg
-        x = self._embed_inputs(p, tokens)
-        new_cache = dict(cache)
-        for i, seg in enumerate(self.segments()):
-            block = make_block(seg.kind, cfg)
-
-            def body(h, scanned):
-                layer_params, layer_cache = scanned
-                return block.prefill_paged(layer_params, h, layer_cache,
-                                           index, page_table)
-
-            x, new_cache[f"seg{i}"] = jax.lax.scan(
-                body, x, (p[f"seg{i}"], cache[f"seg{i}"])
-            )
-        return self._head(p, x), new_cache
+        return self._scan_paged("prefill_paged", p, tokens, cache, index,
+                                page_table)
 
     def verify_step_paged(self, p, tokens, cache, index, page_table, lengths):
         """Score S = k+1 window tokens per slot through the whole stack in
@@ -522,21 +493,8 @@ class DecoderLM(Module):
         if not self.supports_paged():
             raise ValueError(f"{self.cfg.name}: speculative verify needs "
                              "attention-only segments")
-        cfg = self.cfg
-        x = self._embed_inputs(p, tokens)
-        new_cache = dict(cache)
-        for i, seg in enumerate(self.segments()):
-            block = make_block(seg.kind, cfg)
-
-            def body(h, scanned):
-                layer_params, layer_cache = scanned
-                return block.verify_paged(layer_params, h, layer_cache,
-                                          index, page_table, lengths)
-
-            x, new_cache[f"seg{i}"] = jax.lax.scan(
-                body, x, (p[f"seg{i}"], cache[f"seg{i}"])
-            )
-        return self._head(p, x), new_cache
+        return self._scan_paged("verify_paged", p, tokens, cache, index,
+                                page_table, lengths)
 
     # ---- chunked prefill ----
 

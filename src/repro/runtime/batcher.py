@@ -285,6 +285,12 @@ class ContinuousBatcher:
                 dtype=cache_dtype, kv_quant=kv_quant,
             )
 
+            # The prefill, verify and page-copy programs donate the cache
+            # (argument 2; 0 for the page copy): they update the pools where
+            # they lie, and the caller keeps only what they return.  The
+            # decode step does not: its callers may hand back the cache
+            # they passed (the benchmark's stale-state fault does), so its
+            # input stays valid, at one copy of the pools a step.
             def step_paged(params, token, cache, index, table, lengths):
                 return model.decode_step_paged(params, token, cache, index,
                                                table, lengths)
@@ -301,7 +307,7 @@ class ContinuousBatcher:
                     return model.prefill_step_paged(params, tokens, cache,
                                                     index, table)
 
-                self._prefill = jax.jit(prefill_paged)
+                self._prefill = jax.jit(prefill_paged, donate_argnums=2)
             if self.speculate > 0:
 
                 def verify_paged(params, tokens, cache, index, table,
@@ -309,7 +315,7 @@ class ContinuousBatcher:
                     return model.verify_step_paged(params, tokens, cache,
                                                    index, table, lengths)
 
-                self._verify = jax.jit(verify_paged)
+                self._verify = jax.jit(verify_paged, donate_argnums=2)
 
             def copy_page(cache, src, dst):
                 # paged-cache leaves are layer-stacked (n_layers, P, ...):
@@ -318,7 +324,7 @@ class ContinuousBatcher:
                 return jax.tree.map(lambda t: t.at[:, dst].set(t[:, src]),
                                     cache)
 
-            self._copy_page = jax.jit(copy_page)
+            self._copy_page = jax.jit(copy_page, donate_argnums=0)
         else:
             if kv_quant is not None:
                 raise ValueError("kv_quant requires paged=True (the dense "
@@ -766,7 +772,12 @@ class ContinuousBatcher:
         DeviceFailure is transient: the step function is pure, so a retry
         recomputes from unchanged inputs.  Retries beyond the policy
         re-raise — a permanently failing device is not a serving-loop
-        decision."""
+        decision.
+
+        The paged prefill and verify programs donate the cache.  The
+        injected failure raises before the call, so the retry's inputs are
+        intact; a real failure inside such a call would already have
+        consumed the donated pools."""
         fn = fn if fn is not None else self._step
         attempts = 0
         while True:

@@ -204,13 +204,15 @@ class DisaggEngine:
                     lambda d, s: d.at[:, dst_ids].set(s[:, src_ids]),
                     dst_cache, src_cache)
 
-            self._migrate = jax.jit(migrate)
+            # the decode cache is donated and replaced by the result
+            self._migrate = jax.jit(migrate, donate_argnums=0)
 
         def prefill(params, tokens, cache, index, table):
             return model.prefill_step_paged(params, tokens, cache, index,
                                             table)
 
-        self._prefill = jax.jit(prefill)
+        # the cache is donated, like the batcher's own programs
+        self._prefill = jax.jit(prefill, donate_argnums=2)
 
         self.workers = [
             _Worker(wid=w, slot=WORKER_SLOT_BASE + w)

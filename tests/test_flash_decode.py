@@ -1,6 +1,7 @@
-"""Paged split-KV flash decode: kernel-vs-oracle sweeps, paged-vs-dense
-decode parity through the model stack (the acceptance bar: <= 1e-5 in f32
-across ragged batch fills), and chunked-prefill parity."""
+"""Paged split-KV flash decode: kernel-vs-oracle sweeps over layer-stacked
+(L, P, ps, Hkv * d) pools read at a layer index, paged-vs-dense decode
+parity through the model stack (the acceptance bar: <= 1e-5 in f32 across
+ragged batch fills), and chunked-prefill parity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,10 +16,27 @@ from repro.models.layers import Attention
 from repro.runtime.kv_pages import PagePool
 
 
+# the kernel tests read the last layer of a two-layer stack: an index map
+# that dropped the layer would read layer 0's different values
+LAYERS, LAYER = 2, 1
+
+
+def _pages(rng, P, ps, Hkv, d, dtype=jnp.float32, layers=LAYERS):
+    """A random layer-stacked pool (layers, P, ps, Hkv * d)."""
+    shape = (layers, P, ps, Hkv * d)
+    if dtype == jnp.int8:
+        return jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+    return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+def _scales(rng, P, ps, Hkv, lo, hi):
+    return jnp.asarray(rng.uniform(lo, hi, (LAYERS, P, ps, Hkv)), jnp.float32)
+
+
 def _paged_setup(rng, B, Hkv, d, ps, W, lengths, P=None):
     P = P or (B * W + 1)
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, d)), jnp.float32)
+    kp = _pages(rng, P, ps, Hkv, d)
+    vp = _pages(rng, P, ps, Hkv, d)
     pool = PagePool(P - 1, ps)
     for s, ln in enumerate(lengths):
         if ln > 0:
@@ -53,8 +71,8 @@ def test_kernel_matches_oracle(B, H, Hkv, d, ps, W, lengths):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     kp, vp, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, lengths)
-    out = mx_flash_decode(q, kp, vp, table, lns, interpret=True)
-    ref = paged_decode_ref(q, kp, vp, table, lns)
+    out = mx_flash_decode(q, kp, vp, LAYER, table, lns, interpret=True)
+    ref = paged_decode_ref(q, kp, vp, LAYER, table, lns)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     # free slots produce exactly-zero rows
@@ -70,10 +88,10 @@ def test_kernel_scaled_pages_match_oracle():
     B, H, Hkv, d, ps, W = 3, 8, 4, 16, 8, 3
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     P = B * W + 1
-    kp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
+    kp = _pages(rng, P, ps, Hkv, d, jnp.int8)
+    vp = _pages(rng, P, ps, Hkv, d, jnp.int8)
+    ks = _scales(rng, P, ps, Hkv, 0.001, 0.05)
+    vs = _scales(rng, P, ps, Hkv, 0.001, 0.05)
     pool = PagePool(P - 1, ps)
     lengths = (20, 3, 24)
     for s, ln in enumerate(lengths):
@@ -82,8 +100,10 @@ def test_kernel_scaled_pages_match_oracle():
     table = jnp.asarray(pool.page_table(B, W))
     lns = jnp.asarray(pool.lengths(B))
     out = mx_flash_decode(q, kp.astype(jnp.float32), vp.astype(jnp.float32),
-                          table, lns, k_scale=ks, v_scale=vs, interpret=True)
-    ref = paged_decode_ref(q, kp, vp, table, lns, k_scale=ks, v_scale=vs)
+                          LAYER, table, lns, k_scale=ks, v_scale=vs,
+                          interpret=True)
+    ref = paged_decode_ref(q, kp, vp, LAYER, table, lns, k_scale=ks,
+                           v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
@@ -101,14 +121,15 @@ def test_kernel_scaled_pages_across_blocks(B, H, Hkv, d, ps, W, lengths):
     rng = np.random.default_rng(5)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     P = B * W + 1
-    kp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, (P, ps, Hkv, d)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.001, 0.05, (P, ps, Hkv)), jnp.float32)
+    kp = _pages(rng, P, ps, Hkv, d, jnp.int8)
+    vp = _pages(rng, P, ps, Hkv, d, jnp.int8)
+    ks = _scales(rng, P, ps, Hkv, 0.001, 0.05)
+    vs = _scales(rng, P, ps, Hkv, 0.001, 0.05)
     _, _, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, lengths, P)
-    out = mx_flash_decode(q, kp, vp, table, lns, k_scale=ks, v_scale=vs,
-                          interpret=True)
-    ref = paged_decode_ref(q, kp, vp, table, lns, k_scale=ks, v_scale=vs)
+    out = mx_flash_decode(q, kp, vp, LAYER, table, lns, k_scale=ks,
+                          v_scale=vs, interpret=True)
+    ref = paged_decode_ref(q, kp, vp, LAYER, table, lns, k_scale=ks,
+                           v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
     for i, ln in enumerate(lengths):
@@ -156,25 +177,69 @@ def test_stale_page_contents_are_dead():
     B, H, Hkv, d, ps, W = 2, 4, 2, 16, 4, 2
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     kp, vp, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, (6, 3))
-    ref = paged_decode_ref(q, kp, vp, table, lns)
+    ref = paged_decode_ref(q, kp, vp, LAYER, table, lns)
     # poison: rows at positions >= length inside resident pages AND whole
-    # unallocated pages.  Build a mask of live (page, row) coordinates.
-    live = np.zeros(kp.shape[:2], bool)
+    # unallocated pages, and every other layer.  Build a mask of live
+    # (layer, page, row) coordinates.
+    live = np.zeros(kp.shape[:3], bool)
     tbl = np.asarray(table)
     for s, ln in enumerate((6, 3)):
         for j in range(W):
             for r in range(ps):
                 if j * ps + r < ln:
-                    live[tbl[s, j], r] = True
-    mask = jnp.asarray(live)[:, :, None, None]
+                    live[LAYER, tbl[s, j], r] = True
+    mask = jnp.asarray(live)[..., None]
     poison_k = jnp.where(mask, kp, 1e30)
     poison_v = jnp.where(mask, vp, 1e30)
-    out = paged_decode_ref(q, poison_k, poison_v, table, lns)
+    out = paged_decode_ref(q, poison_k, poison_v, LAYER, table, lns)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
-    outk = mx_flash_decode(q, poison_k, poison_v, table, lns, interpret=True)
+    outk = mx_flash_decode(q, poison_k, poison_v, LAYER, table, lns,
+                           interpret=True)
     np.testing.assert_allclose(np.asarray(outk), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_kernel_reads_only_its_layer():
+    """Two layers hold different values in the same pages: at each layer
+    the kernel matches the oracle on that layer alone, and poisoning every
+    other layer changes nothing."""
+    rng = np.random.default_rng(6)
+    B, H, Hkv, d, ps, W = 3, 8, 2, 32, 8, 4
+    lengths = (29, 0, 11)
+    q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
+    kp, vp, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, lengths)
+    outs = []
+    for layer in range(LAYERS):
+        out = mx_flash_decode(q, kp, vp, layer, table, lns, interpret=True)
+        alone = paged_decode_ref(q, kp[layer:layer + 1], vp[layer:layer + 1],
+                                 0, table, lns)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(alone),
+                                   rtol=1e-5, atol=1e-5)
+        other = (jnp.arange(LAYERS) != layer)[:, None, None, None]
+        poisoned = mx_flash_decode(q, jnp.where(other, 1e30, kp),
+                                   jnp.where(other, 1e30, vp), layer, table,
+                                   lns, interpret=True)
+        np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(out))
+        outs.append(np.asarray(out))
+    assert not np.allclose(outs[0], outs[1])
+
+
+@pytest.mark.parametrize("H,Hkv,d", [(14, 2, 64), (64, 8, 128)],
+                         ids=["hkv2x64", "hkv8x128"])
+def test_kernel_f32_parity(H, Hkv, d):
+    """f32 pools at the served row widths (128 and 1024 lanes): the kernel
+    agrees with `paged_decode_ref` to f32 rounding, over ragged lengths
+    that span several blocks, a free slot among them."""
+    rng = np.random.default_rng(7)
+    B, ps, W = 4, 16, 12
+    lengths = (170, 0, 192, 1)
+    q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
+    kp, vp, table, lns = _paged_setup(rng, B, Hkv, d, ps, W, lengths)
+    out = mx_flash_decode(q, kp, vp, LAYER, table, lns, interpret=True)
+    ref = paged_decode_ref(q, kp, vp, LAYER, table, lns)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-6, atol=2e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -209,7 +274,7 @@ def test_attention_paged_matches_dense(backend):
             table = jnp.asarray(pool.page_table(B, width))
             lns = jnp.asarray(pool.lengths(B))
             od, dense = attn.decode(p, x, dense, jnp.asarray(idx))
-            op, paged = attn.decode_paged(p, x, paged, jnp.asarray(idx),
+            op, paged = attn.decode_paged(p, x, paged, 0, jnp.asarray(idx),
                                           table, lns)
             np.testing.assert_allclose(np.asarray(od), np.asarray(op),
                                        rtol=1e-5, atol=1e-5)
@@ -239,8 +304,8 @@ def test_attention_paged_int8_roundtrip():
             pool.set_length(s, t + 1)
         table = jnp.asarray(pool.page_table(B, width))
         lns = jnp.asarray(pool.lengths(B))
-        of, f32c = attn.decode_paged(p, x, f32c, idx, table, lns)
-        oq, q8c = attn.decode_paged(p, x, q8c, idx, table, lns)
+        of, f32c = attn.decode_paged(p, x, f32c, 0, idx, table, lns)
+        oq, q8c = attn.decode_paged(p, x, q8c, 0, idx, table, lns)
         err = float(jnp.abs(of - oq).max())
         scale = float(jnp.abs(of).max())
         assert err < 0.05 * max(scale, 1.0), (t, err, scale)
@@ -344,16 +409,7 @@ def test_chunked_prefill_matches_token_stepping():
 def _verify_setup(rng, B, Hkv, d, ps, W, lengths, S):
     """Pages are random EVERYWHERE — rows past each slot's live length are
     stale garbage the masks must keep dead."""
-    P = B * W + 1
-    kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, d)), jnp.float32)
-    pool = PagePool(P - 1, ps)
-    for s, ln in enumerate(lengths):
-        if ln > 0:
-            pool.reserve(s, ln)
-            pool.set_length(s, ln)
-    table = jnp.asarray(pool.page_table(B, W))
-    return kp, vp, table, jnp.asarray(pool.lengths(B))
+    return _paged_setup(rng, B, Hkv, d, ps, W, lengths)
 
 
 def _verify_oracle(q, kp, vp, table, lns, *, k_scale=None, v_scale=None):
@@ -362,7 +418,7 @@ def _verify_oracle(q, kp, vp, table, lns, *, k_scale=None, v_scale=None):
     from repro.kernels.ref import paged_prefill_ref
 
     S = q.shape[1]
-    ref = paged_prefill_ref(q, kp, vp, table, lns - S,
+    ref = paged_prefill_ref(q, kp, vp, LAYER, table, lns - S,
                             k_scale=k_scale, v_scale=v_scale)
     return jnp.where((lns > 0)[:, None, None, None], ref, 0.0)
 
@@ -386,7 +442,7 @@ def test_verify_kernel_matches_prefill_oracle(B, H, Hkv, d, ps, W, S, lengths):
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, S, H, d)), jnp.float32)
     kp, vp, table, lns = _verify_setup(rng, B, Hkv, d, ps, W, lengths, S)
-    out = mx_flash_verify(q, kp, vp, table, lns, interpret=True)
+    out = mx_flash_verify(q, kp, vp, LAYER, table, lns, interpret=True)
     ref = _verify_oracle(q, kp, vp, table, lns)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -405,8 +461,9 @@ def test_verify_s1_matches_decode_kernel():
     lengths = (7, 0, 20)
     q = jnp.asarray(rng.standard_normal((B, H, d)), jnp.float32)
     kp, vp, table, lns = _verify_setup(rng, B, Hkv, d, ps, W, lengths, 1)
-    ver = mx_flash_verify(q[:, None], kp, vp, table, lns, interpret=True)
-    dec = mx_flash_decode(q, kp, vp, table, lns, interpret=True)
+    ver = mx_flash_verify(q[:, None], kp, vp, LAYER, table, lns,
+                          interpret=True)
+    dec = mx_flash_decode(q, kp, vp, LAYER, table, lns, interpret=True)
     np.testing.assert_allclose(np.asarray(ver[:, 0]), np.asarray(dec),
                                rtol=1e-6, atol=1e-6)
 
@@ -421,11 +478,11 @@ def test_verify_scaled_pages_match_oracle():
     lengths = (11, 24)
     q = jnp.asarray(rng.standard_normal((B, S, H, d)), jnp.float32)
     kp, vp, table, lns = _verify_setup(rng, B, Hkv, d, ps, W, lengths, S)
-    P = kp.shape[0]
-    ks = jnp.asarray(rng.uniform(0.5, 2.0, (P, ps, Hkv)), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.5, 2.0, (P, ps, Hkv)), jnp.float32)
-    out = mx_flash_verify(q, kp, vp, table, lns, k_scale=ks, v_scale=vs,
-                          interpret=True)
+    P = kp.shape[1]
+    ks = _scales(rng, P, ps, Hkv, 0.5, 2.0)
+    vs = _scales(rng, P, ps, Hkv, 0.5, 2.0)
+    out = mx_flash_verify(q, kp, vp, LAYER, table, lns, k_scale=ks,
+                          v_scale=vs, interpret=True)
     ref = _verify_oracle(q, kp, vp, table, lns, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
@@ -441,13 +498,14 @@ def test_verify_causal_within_window():
     lengths = (9,)
     q = jnp.asarray(rng.standard_normal((B, S, H, d)), jnp.float32)
     kp, vp, table, lns = _verify_setup(rng, B, Hkv, d, ps, W, lengths, S)
-    base = np.asarray(mx_flash_verify(q, kp, vp, table, lns, interpret=True))
+    base = np.asarray(mx_flash_verify(q, kp, vp, LAYER, table, lns,
+                                      interpret=True))
     # position of the LAST window row is lengths-1 = 8 -> page 2, row 0
     tbl = np.asarray(table)
     pg, row = tbl[0, 8 // ps], 8 % ps
-    kp2 = kp.at[pg, row].set(99.0)
-    vp2 = vp.at[pg, row].set(-99.0)
-    pert = np.asarray(mx_flash_verify(q, kp2, vp2, table, lns,
+    kp2 = kp.at[LAYER, pg, row].set(99.0)
+    vp2 = vp.at[LAYER, pg, row].set(-99.0)
+    pert = np.asarray(mx_flash_verify(q, kp2, vp2, LAYER, table, lns,
                                       interpret=True))
     np.testing.assert_array_equal(pert[:, :2], base[:, :2])
     assert not np.allclose(pert[:, 2], base[:, 2])

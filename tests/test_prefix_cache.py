@@ -382,6 +382,50 @@ def test_dense_paged_prefix_outputs_exactly_equal(model_and_params):
     assert pref.pool_stats().pages_in_use == pref.prefix.entries
 
 
+# what the five shared-prefix requests of the test below were served when
+# each layer's pool was its own (P, ps, Hkv, hd) slice of the stack and no
+# program donated it
+_SERVED_BEFORE_DONATION = {0: [198, 198, 198, 198], 1: [33, 35, 235, 245],
+                           2: [194, 194, 194, 194], 3: [32, 198, 198, 198],
+                           4: [123, 198, 198, 198]}
+
+
+def test_donated_programs_consume_the_cache_they_are_given(model_and_params):
+    """Chunked prefill and the prefix cache's page copy donate the cache:
+    each call consumes the pools it is handed, and the batcher serves on
+    from the pools it gets back and never reads a consumed one (that would
+    raise).  The tokens served through prefill and COW are those served
+    before the pools were stacked lane-dense and donated."""
+    cfg, model, params = model_and_params
+    pref = ContinuousBatcher(model, params, batch_slots=2, max_len=20,
+                             paged=True, page_size=4, num_pages=40,
+                             prefix_cache=True, prefill_chunk=4)
+    handed = {"_prefill": [], "_copy_page": []}
+
+    def spy(name, at):
+        program = getattr(pref, name)
+
+        def call(*args):
+            out = program(*args)
+            handed[name].append(args[at])
+            return out
+        setattr(pref, name, call)
+
+    spy("_prefill", 2)
+    spy("_copy_page", 0)
+    for r in _shared_prefix_requests(cfg, plen=14, frac=0.75, n=5,
+                                     max_new=4, seed=2):
+        pref.submit(r)
+    got = {k: v.output for k, v in pref.run_to_completion().items()}
+    assert got == _SERVED_BEFORE_DONATION
+    assert pref.prefix_stats()["cow_copies"] == len(handed["_copy_page"]) >= 1
+    assert len(handed["_prefill"]) >= 5
+    for caches in handed.values():
+        assert all(leaf.is_deleted() for cache in caches
+                   for leaf in jax.tree.leaves(cache))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(pref.cache))
+
+
 @pytest.mark.slow
 def test_prefix_cache_under_pool_pressure(model_and_params):
     """A tight pool forces index eviction during admission; everything

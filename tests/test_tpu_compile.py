@@ -1,6 +1,7 @@
 """The main path's Pallas kernels compile for a TPU v5e at llama3.2-1b's
 published widths, at the plans the dispatch picks itself, and the decode
-attention and whole decode step at qwen2-0.5b's chat-cell shapes.
+attention and whole donated decode and prefill programs at the chat cells'
+shapes: qwen2-0.5b on one chip, deepseek-67b per chip of a (1, 4) mesh.
 
 Nothing here runs: each test lowers and compiles for a *described* chip
 (`jax.experimental.topologies`), which raises what the chip's compiler
@@ -10,7 +11,9 @@ topology is described inside a fixture, never at import, so every xdist
 worker collects the same tests and only the worker running this file loads
 the TPU compiler.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -130,10 +133,11 @@ def test_paged_attention_compiles(one_chip, S, dtype):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     q = sds((B, CFG.n_heads, HD) if S == 1 else (B, S, CFG.n_heads, HD))
-    kv = sds((pages, PAGE, CFG.n_kv_heads, HD))
+    kv = sds((2, pages, PAGE, CFG.n_kv_heads * HD))
     table, lengths = sds((B, W), jnp.int32), sds((B,), jnp.int32)
     kernel = mx_flash_decode if S == 1 else mx_flash_verify
-    census = _compile(lambda *a: kernel(*a), q, kv, kv, table, lengths)
+    census = _compile(lambda *a: kernel(*a), q, kv, kv, sds((), jnp.int32),
+                      table, lengths)
     assert census == {kernel.__name__: 1}
 
 
@@ -142,16 +146,18 @@ def test_int8_paged_decode_compiles(one_chip):
     B, W, pages = 8, 34, 8 * 34 + 1
     q = jax.ShapeDtypeStruct((B, CFG.n_heads, HD), jnp.float32,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((pages, PAGE, CFG.n_kv_heads, HD), jnp.int8,
-                              sharding=one_chip)
-    sc = jax.ShapeDtypeStruct((pages, PAGE, CFG.n_kv_heads), jnp.float32,
+    kv = jax.ShapeDtypeStruct((2, pages, PAGE, CFG.n_kv_heads * HD),
+                              jnp.int8, sharding=one_chip)
+    sc = jax.ShapeDtypeStruct((2, pages, PAGE, CFG.n_kv_heads), jnp.float32,
                               sharding=one_chip)
     i32 = jax.ShapeDtypeStruct((B, W), jnp.int32, sharding=one_chip)
     ln = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
-    def fn(q, k, v, t, n, ks, vs):
-        return mx_flash_decode(q, k, v, t, n, k_scale=ks, v_scale=vs)
-    assert _compile(fn, q, kv, kv, i32, ln, sc, sc) == {"mx_flash_decode": 1}
+    def fn(q, k, v, lay, t, n, ks, vs):
+        return mx_flash_decode(q, k, v, lay, t, n, k_scale=ks, v_scale=vs)
+    assert _compile(fn, q, kv, kv, layer, i32, ln, sc, sc) == {
+        "mx_flash_decode": 1}
 
 
 QWEN = get_config("qwen2-0.5b")
@@ -161,27 +167,50 @@ QWEN_PAGES = 12289  # the chat cell's pool and its dump page
 @pytest.mark.parametrize("W", [128, 192])
 def test_qwen_decode_attention_compiles(one_chip, W):
     """qwen2-0.5b's decode attention at the chat cell's shapes: 64 slots,
-    page 16, Hkv 2, 12,289 pages, at both table widths the cell reaches."""
+    page 16, Hkv 2, 12,289 pages a layer of 24, at both table widths the
+    cell reaches."""
     B = 64
 
     def sds(shape, dt=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     q = sds((B, QWEN.n_heads, QWEN.hd))
-    kv = sds((QWEN_PAGES, PAGE, QWEN.n_kv_heads, QWEN.hd))
+    kv = sds((QWEN.n_layers, QWEN_PAGES, PAGE, QWEN.n_kv_heads * QWEN.hd))
     census = _compile(lambda *a: mx_flash_decode(*a), q, kv, kv,
-                      sds((B, W), jnp.int32), sds((B,), jnp.int32))
+                      sds((), jnp.int32), sds((B, W), jnp.int32),
+                      sds((B,), jnp.int32))
     assert census == {"mx_flash_decode": 1}
 
 
-def test_qwen_decode_step_reads_the_pools_in_place(one_chip, pol):
-    """The whole qwen2-0.5b decode step, as the chat cell runs it (f32
-    pools of 12,289 pages, 64 slots, W 128): one decode attention launch,
-    and no more pool-sized copies than the layer body's four (each of K
-    and V sliced out of the layer stack and put back), so the kernel takes
-    its pools as they lie."""
-    import re
+_MOVES = ("copy", "copy-done", "dynamic-slice", "dynamic-update-slice", "pad")
 
+
+def _pool_moves(text, pool_shape):
+    """The compiled program's instructions that move the pool: a copy,
+    slice, update-slice or pad (by opcode, or by the name of the fusion
+    that wraps one) whose result holds as many elements as the stacked
+    pool or one layer of it."""
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    moves = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(",
+                     line)
+        if not m or math.prod(map(int, m[2].split(","))) not in sizes:
+            continue
+        if m[3] in _MOVES or any(w in m[1] for w in _MOVES):
+            moves.append(line.strip()[:200])
+    return moves
+
+
+def _serving_program(model, name, args, donate=True):
+    """``name`` of the model's paged programs, compiled with the cache
+    (argument 2) donated or not."""
+    fn = getattr(model, name)
+    return jax.jit(fn, donate_argnums=(2,) if donate else ()).lower(
+        *args).compile()
+
+
+def _qwen_program(one_chip, pol, name, W, donate=True):
     from repro.models import build_model
 
     model = build_model(QWEN)
@@ -189,23 +218,106 @@ def test_qwen_decode_step_reads_the_pools_in_place(one_chip, pol):
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    B, W = 64, 128
-    i32 = jnp.int32
+    def i32(*shape):
+        return sds(jax.ShapeDtypeStruct(shape, jnp.int32))
+
     with ops.use_policy(pol):
         params = jax.tree.map(sds, model.abstract())
         cache = jax.tree.map(sds, model.make_paged_cache(
             QWEN_PAGES, PAGE, mode="abstract", dtype=jnp.float32))
-        text = jax.jit(model.decode_step_paged).lower(
-            params, sds(jax.ShapeDtypeStruct((B, 1), i32)), cache,
-            sds(jax.ShapeDtypeStruct((B,), i32)),
-            sds(jax.ShapeDtypeStruct((B, W), i32)),
-            sds(jax.ShapeDtypeStruct((B,), i32))).compile().as_text()
+        if name == "decode_step_paged":
+            B = 64
+            args = (params, i32(B, 1), cache, i32(B), i32(B, W), i32(B))
+        else:  # one 128-token chunk of one slot
+            args = (params, i32(1, 128), cache, i32(1), i32(1, W))
+        compiled = _serving_program(model, name, args, donate)
+    pool = cache["seg0"]["k_pages"]
+    return compiled, pool.shape, sum(a.size * a.dtype.itemsize
+                                     for a in jax.tree.leaves(cache))
+
+
+def test_qwen_decode_step_reads_the_pools_in_place(one_chip, pol):
+    """The whole qwen2-0.5b decode step at the chat cell's shapes (f32
+    pools of 24 layers x 12,289 pages, 64 slots).  Donated, at both table
+    widths the cell reaches: one decode attention launch, no copy, slice,
+    update-slice or pad of the pool or of a layer of it, and the pools'
+    every byte aliased from input to output — the step writes and reads
+    the pools where they lie.  Not donated, as the batcher runs it: the
+    layer loop still moves nothing, and the only moves are one copy of
+    each pool into the step's output."""
+    for W in (128, 192):
+        compiled, shape, pool_bytes = _qwen_program(
+            one_chip, pol, "decode_step_paged", W)
+        text = compiled.as_text()
+        assert kernel_launches(text)["mx_flash_decode"] == 1
+        assert _pool_moves(text, shape) == []
+        assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    compiled, shape, _ = _qwen_program(one_chip, pol, "decode_step_paged",
+                                       128, donate=False)
+    moves = _pool_moves(compiled.as_text(), shape)
+    assert len(moves) == 2, moves
+    assert all(re.search(r" copy\(%cache\S*_pages", m) for m in moves), moves
+
+
+@pytest.mark.parametrize("W", [128, 192])
+def test_qwen_prefill_chunk_reads_the_pools_in_place(one_chip, pol, W):
+    """The donated 128-token prefill chunk at the chat cell's shapes: the
+    same two checks as the decode step."""
+    compiled, shape, pool_bytes = _qwen_program(
+        one_chip, pol, "prefill_step_paged", W)
+    assert _pool_moves(compiled.as_text(), shape) == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_deepseek_tp4_decode_step_reads_the_pools_in_place(topo, pol):
+    """deepseek-67b's donated decode step as its four-chip cell runs it:
+    8 layers at the published widths, TP 4 on the ring of a (1, 4) mesh,
+    pools of 6,145 pages sharded on their kv-head lanes (256 of 1024 a
+    chip).  Per chip: one decode attention launch, no pool move, and the
+    chip's share of the pools aliased."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.models import build_model
+    from repro.parallel.sharding import (
+        collective_policy, make_rules, tree_shardings, use_rules)
+
+    L, B, W, pages = 8, 32, 128, 6145
+    cfg = dataclasses.replace(get_config("deepseek-67b"), n_layers=L,
+                              blocks=(("dense", L),))
+    model = build_model(cfg)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    rules = make_rules(mesh, profile=cfg.parallelism)
+
+    def placed(tree, axes):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, tree_shardings(rules, tree, axes))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, P()))
+
+    with use_rules(rules), collective_policy(mesh), ops.use_policy(pol):
+        params = placed(model.abstract(), model.axes())
+        cache = placed(
+            model.make_paged_cache(pages, PAGE, mode="abstract",
+                                   dtype=jnp.float32),
+            model.make_paged_cache(pages, PAGE, mode="axes"))
+        compiled = _serving_program(
+            model, "decode_step_paged",
+            (params, i32(B, 1), cache, i32(B), i32(B, W), i32(B)))
+    text = compiled.as_text()
+    shard = (L, pages, PAGE, cfg.n_kv_heads * cfg.hd // 4)
+    assert cache["seg0"]["k_pages"].sharding.shard_shape(
+        cache["seg0"]["k_pages"].shape) == shard
     assert kernel_launches(text)["mx_flash_decode"] == 1
-    pool = f"{QWEN_PAGES},{PAGE},{QWEN.n_kv_heads},{QWEN.hd}]"
-    copies = [line for line in text.splitlines()
-              if re.search(r"= \S+ copy\(", line)
-              and pool in line.split(" copy(")[0]]
-    assert 0 < len(copies) <= 4, copies
+    assert _pool_moves(text, shard) == []
+    per_chip = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(cache)) // 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= per_chip
 
 
 def test_grouped_matmul_compiles(one_chip, pol):
