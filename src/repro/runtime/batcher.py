@@ -1,23 +1,33 @@
 """Continuous-batching serving scheduler with a fault-tolerant lifecycle.
 
-Fixed B decode slots; requests stream in, each slot decodes at its own
-position (the per-slot `index` vector threaded through Attention.decode).
-When a slot finishes, it is evicted and the next queued request is
-admitted — its prompt is prefilled by stepping tokens through the slot
-while the other slots keep decoding (token-level interleaving, vLLM-style
-scheduling at batch granularity).
+Fixed B slots; requests stream in, each slot at its own cache position
+(the per-slot `index` vector threaded through Attention.decode).  When a
+slot finishes, it is evicted and the next queued request is admitted; its
+prompt rides the same steps as the other slots' answers (token-level
+interleaving, vLLM-style scheduling at batch granularity).
+
+One step path.  Each step admits, then feeds every live slot a window of
+S = speculate + 1 rows through ONE device launch, and keeps what the argmax
+of each row accepts (`ContinuousBatcher._window`).  A window's first rows
+carry committed tokens: the slot's next prompt tokens, or its last output.
+S = 1 is the decode step, through `_step`.  With S > 1, drafter proposals
+ride behind the committed rows and `_verify` scores them in the same
+launch; its row 0 reproduces the decode step bitwise.  Which of the two
+programs runs is fixed by ``speculate`` at construction.
 
 Two cache backends:
 
   - dense (default): the (slots, max_len) rectangle; every step streams the
-    full padded cache and every eviction zeroes max_len rows.
+    full padded cache and every eviction zeroes max_len rows.  The step is
+    `model.decode_step`, given no page table or lengths.
   - paged (``paged=True``): a flat page pool + per-slot page tables
     (runtime/kv_pages).  Admission reserves ceil(expected_tokens/page_size)
     pages from the free list (back-pressuring the queue when the pool is
     exhausted instead of crashing), eviction returns them with NO zeroing,
-    and each decode step attends only over pages the live sequences
-    actually touch.  The device step is `model.decode_step_paged`
-    (kernels/mx_flash_decode under the pallas_mx policy).
+    and each step attends only over pages the live sequences actually
+    touch.  The step is `model.decode_step_paged` (kernels/mx_flash_decode
+    under the pallas_mx policy), or `model.verify_step_paged`
+    (mx_flash_verify) when speculating.
 
 Two paged admission accelerators (the cross-request reuse PR):
 
@@ -29,8 +39,8 @@ Two paged admission accelerators (the cross-request reuse PR):
   - ``prefill_chunk=N``: admission pushes the unmatched tail through
     `model.prefill_step_paged` N tokens per launch, writing K/V directly
     into the slot's pages.  The prompt's LAST token always rides the
-    ordinary decode step, so the first generated token's launch is
-    identical across all admission paths.
+    step's window, so the first generated token's launch is identical
+    across all admission paths.
 
 The fault-tolerant lifecycle (runtime/lifecycle) on top of both:
 
@@ -73,13 +83,17 @@ spans cost about a microsecond each and are recorded only while the
 profiler runs; the records are always kept.  The jitted decode and
 prefill programs are the functions `DECODE_PROGRAM` and `PREFILL_PROGRAM`
 name (XLA modules ``jit_step_paged`` and ``jit_prefill_paged``), names
-that trace readers key on; tests/test_step_records.py lowers both.
+that trace readers key on; tests/test_step_records.py lowers both.  The
+prefill, verify and page-copy programs donate the cache; the decode step
+does not, since a caller may hand back the cache it passed (the
+benchmark's stale-state fault does), at one copy of the pools a step.
 
 CPU-testable end to end with smoke configs (tests/test_batcher.py asserts
-outputs are identical to per-request isolated decoding; tests/test_kv_pages
-asserts dense/paged parity; tests/test_prefix_cache asserts dense == paged
-== prefix-shared; tests/test_lifecycle.py asserts preempt->resume and
-under-chaos exactness)."""
+outputs are identical to per-request isolated decoding, and pins what the
+decode program is fed; tests/test_kv_pages asserts dense/paged parity;
+tests/test_prefix_cache asserts dense == paged == prefix-shared;
+tests/test_lifecycle.py asserts preempt->resume and under-chaos
+exactness)."""
 
 from __future__ import annotations
 
@@ -138,6 +152,8 @@ class _Slot:
         self.prompt_left = 0   # tokens of seq still to feed
         self.seq: Optional[np.ndarray] = None  # prompt + prior output
         self.admit_order = 0   # preemption tie-break: newest victim first
+        self.fed = 1           # rows of this step's window fed committed
+        self.drafts = ()       # draft tokens riding behind them this step
 
     @property
     def free(self) -> bool:
@@ -150,6 +166,9 @@ def _next_pow2(n: int) -> int:
 
 class ContinuousBatcher:
     """model: DecoderLM; params: its params; B slots; max_len cache.
+
+    Each `step` admits, then runs one window of S = speculate + 1 rows per
+    live slot through one launch (`_window`); S = 1 is the decode step.
 
     ``paged=True`` switches to the paged KV cache: ``page_size`` tokens per
     page, ``num_pages`` allocatable pages (default: enough for every slot
@@ -170,19 +189,17 @@ class ContinuousBatcher:
     logits, so the fault-free hot path skips it by default —
     ``nonfinite_guard=True`` forces it on).
 
-    ``speculate=k`` (paged only) switches decode to speculative windows: a
-    ``drafter`` (runtime/speculative; default NGramDrafter) proposes up to
-    k tokens per decode-phase slot and a single batched verify launch
-    (`model.verify_step_paged` -> `mx_flash_verify`) scores all k+1 window
-    positions; drafts matching the verify argmax chain publish (greedy-
-    exact — the emitted stream is bitwise-identical to speculate=0),
-    rejected drafts roll back by NOT advancing the slot's position/length:
-    their K/V rows sit stale in the slot's already-reserved private tail
-    pages until real tokens overwrite them — zero copies, zero page
-    churn.  Slots still prefilling ride the same launch as forced-token
-    windows (prompt rows are accepted by construction), so speculation
-    composes with chunked prefill, preemption (a resumed request re-enters
-    through prefill windows) and chaos quarantine unchanged.
+    ``speculate=k`` (paged only) widens every step's window to k+1 rows:
+    a ``drafter`` (runtime/speculative; default NGramDrafter) proposes up
+    to k tokens behind each slot's last committed row, and the verify
+    launch (`model.verify_step_paged` -> `mx_flash_verify`) scores them
+    with it.  Drafts matching the argmax chain publish (greedy-exact: the
+    emitted stream is bitwise-identical to speculate=0); rejected drafts
+    roll back by NOT advancing the slot's position/length, their K/V rows
+    left stale in the slot's already-reserved pages until real tokens
+    overwrite them — zero copies, zero page churn.  Slots still prefilling
+    feed up to k+1 prompt tokens as forced rows, so speculation composes
+    with chunked prefill, preemption and chaos quarantine unchanged.
 
     ``abft=True`` arms silent-data-corruption detection end to end: the
     device step traces under `kernels.abft.use_abft()` (every pallas_mx
@@ -208,7 +225,6 @@ class ContinuousBatcher:
                  chaos: Optional[ChaosInjector] = None,
                  retry: Optional[RetryPolicy] = None,
                  nonfinite_guard: Optional[bool] = None,
-                 straggler: Optional[StragglerDetector] = None,
                  speculate: int = 0,
                  drafter: Optional[DraftProposer] = None,
                  abft: bool = False):
@@ -246,7 +262,7 @@ class ContinuousBatcher:
         self.retry = retry or RetryPolicy()
         self.guard = bool(nonfinite_guard) if nonfinite_guard is not None \
             else chaos is not None
-        self.watchdog = straggler or StragglerDetector()
+        self.watchdog = StragglerDetector()
         self.steps_run = 0
         self.health: Deque[StepHealth] = deque(maxlen=4096)
         self.preemptions_total = 0
@@ -600,12 +616,12 @@ class ContinuousBatcher:
         """Chunked prefill directly into the slot's pages: positions
         [s.pos, len(seq)-1) go through `prefill_step_paged`, prefill_chunk
         tokens per launch.  The last token is deliberately LEFT to the
-        decode interleave — its decode launch both writes the final row
-        and produces the next-token logits, identically to the
-        token-stepping path (and, for a preempted request being resumed,
-        identically to the step the preemption interrupted).  An over-long
+        step's window — its launch both writes the final row and produces
+        the next-token logits, identically to the token-stepping path
+        (and, for a preempted request being resumed, identically to the
+        step the preemption interrupted).  An over-long
         prompt (reservation clipped to max_len) prefills only up to the
-        last reserved row; the decode interleave then writes that row and
+        last reserved row; the step's window then writes that row and
         trips the same out-of-room truncation the token-stepping path
         degrades through."""
         cap = len(self.pool.owned(i)) * self.page_size
@@ -749,14 +765,24 @@ class ContinuousBatcher:
                      if self.abft else None),
         }
 
-    def _active_width(self) -> int:
-        """Page-table width covering the deepest live slot, bucketed to the
-        next power of two: the decode step's gather/grid scales with pages
-        actually in use instead of max_len/page_size, while the bucketing
-        bounds jit retraces to O(log) distinct widths."""
-        deepest = max((s.pos + 1 for s in self.slots if not s.free), default=1)
-        return min(_next_pow2(self.pool.pages_for(deepest)),
-                   self._table_width)
+    def _width(self, S: int) -> int:
+        """Page-table width for a window of S rows.  It covers the deepest
+        live slot's last window row (position pos + S - 1), so a pad row
+        past a slot's pages writes to the dump page, never to a clamped
+        real page.  Beyond that it is bucketed to the next power of two,
+        capped at max_len's pages: the step's gather and grid scale with
+        the pages in use, and jit traces O(log) distinct widths."""
+        deepest = max(s.pos for s in self.slots if not s.free)
+        need = self.pool.pages_for(deepest + S)
+        return max(need, min(_next_pow2(need), self._table_width))
+
+    def _room(self, i: int) -> int:
+        """Rows slot i may hold: its reserved pages' on the paged backend
+        (clipped to min(max_len, prompt + max_new) at admission), max_len
+        in the dense rectangle."""
+        if self.paged:
+            return len(self.pool.owned(i)) * self.page_size
+        return self.max_len
 
     def _attn_pages(self, lengths: np.ndarray) -> int:
         """Pages the step's paged attention reads: each live slot's
@@ -767,18 +793,17 @@ class ContinuousBatcher:
     # the step
     # ------------------------------------------------------------------
 
-    def _device_step(self, args, fail_first: bool, fn=None):
+    def _device_step(self, args, fail_first: bool, fn):
         """One device step under the retry policy.  The injected (or real)
         DeviceFailure is transient: the step function is pure, so a retry
         recomputes from unchanged inputs.  Retries beyond the policy
         re-raise — a permanently failing device is not a serving-loop
         decision.
 
-        The paged prefill and verify programs donate the cache.  The
-        injected failure raises before the call, so the retry's inputs are
-        intact; a real failure inside such a call would already have
-        consumed the donated pools."""
-        fn = fn if fn is not None else self._step
+        The paged verify program donates the cache.  The injected failure
+        raises before the call, so the retry's inputs are intact; a real
+        failure inside such a call would already have consumed the donated
+        pools."""
         attempts = 0
         while True:
             try:
@@ -830,9 +855,9 @@ class ContinuousBatcher:
         return host
 
     def step(self) -> int:
-        """One batched step across all slots — a decode step, or a
-        speculative verify window when ``speculate`` > 0; returns #active
-        slots.  Records the step's `StepHealth` and its phase spans."""
+        """One batched step across all slots: admission, then one window
+        through the device program; returns #active slots.  Records the
+        step's `StepHealth` and its phase spans."""
         now = self.steps_run
         health = StepHealth(step=now, t_start=time.perf_counter())
         launches, prompt_tokens = self.prefill_launches, self.prefill_tokens
@@ -850,74 +875,99 @@ class ContinuousBatcher:
                 health.pages_free = self.pool.pages_free
             ran = self.active > 0
             if ran:
-                if self.speculate:
-                    self._verify_window(health)
-                else:
-                    self._decode(health)
+                self._window(health)
             self._flush_health(health, ran_device_step=ran)
         return self.active
 
-    def _decode(self, health: StepHealth):
-        """The plain step's device work and emission: each live slot feeds
-        one token (its next prompt token, or its last output) through the
-        decode program and takes the argmax of its logits."""
+    def _window(self, health: StepHealth):
+        """The step's device work and emission: every live slot feeds a
+        window of S = speculate + 1 rows through one launch, and the host
+        keeps what the argmax of each row accepts.  S = 1 is the decode
+        step (`_step`); S > 1 is the speculative verify (`_verify`).
+
+        A slot's window starts at its next cache position.  Its first
+        ``s.fed`` rows carry committed tokens: the next prompt tokens while
+        it prefills (up to S and its reserved room), else its last output.
+        Once the window reaches the end of the prompt, the drafter's
+        proposals ride behind (`_draft`); the rest are zero pad rows, whose
+        writes land past the slot's length in its own reserved pages or on
+        the dump page, dead under the length mask.
+
+        Acceptance keeps the fed rows and each draft that matches the
+        argmax chain, and publishes them by advancing the slot's position
+        and pool length.  A rejected draft's rows are never published: the
+        rollback copies nothing, and no page moves (pages are reserved
+        worst-case at admission).  Every finish path, the prefix publish,
+        the quarantine and the retry policy are the same for every S, so
+        the emitted stream is bitwise-identical to speculate=0."""
         now = health.step
+        S = self.speculate + 1
         with _Phase(health, "prepare"):
-            tokens = np.zeros((self.B, 1), np.int32)
+            tokens = np.zeros((self.B, S), np.int32)
             index = np.zeros((self.B,), np.int32)
+            lengths = np.zeros((self.B,), np.int32)
             for i, s in enumerate(self.slots):
                 if s.free:
                     continue
-                req = s.req
-                if s.prompt_left > 0:  # prefill phase: feed the next token
-                    tokens[i, 0] = s.seq[len(s.seq) - s.prompt_left]
-                else:  # decode phase: feed the last generated token
-                    tokens[i, 0] = req.output[-1]
+                s.fed, s.drafts = 1, ()
+                if s.prompt_left > 0:
+                    if S > 1:  # one row always fits: the plain step skips this
+                        s.fed = min(S, s.prompt_left, self._room(i) - s.pos)
+                    start = len(s.seq) - s.prompt_left
+                    tokens[i, :s.fed] = s.seq[start:start + s.fed]
+                else:
+                    tokens[i, 0] = s.req.output[-1]
+                if s.fed < S and s.fed >= s.prompt_left:  # rows to spare
+                    s.drafts = self._draft(i, s, S)
+                    tokens[i, s.fed:s.fed + len(s.drafts)] = s.drafts
                 index[i] = s.pos
+                # the kernel's row-r mask is kpos <= lengths-S+r: pos+S
+                # makes row r attend exactly through its own position
+                lengths[i] = s.pos + S
             fail = self.chaos.wants_failure(now) if self.chaos else False
             args = (self.params, jnp.asarray(tokens), self.cache,
                     jnp.asarray(index))
             if self.paged:
-                for i, s in enumerate(self.slots):
-                    if not s.free:
-                        self.pool.set_length(i, s.pos + 1)
-                health.width = self._active_width()
-                lengths = self.pool.lengths(self.B)
+                health.width = self._width(S)
                 health.attn_pages = self._attn_pages(lengths)
                 args += (jnp.asarray(self.pool.page_table(self.B,
                                                           health.width)),
                          jnp.asarray(lengths))
         with _Phase(health, "dispatch"):
-            (logits, self.cache), health.retries = self._device_step(args,
-                                                                     fail)
-            last = logits[:, -1]
-            best = None if self.abft else jnp.argmax(last, axis=-1)
+            # read per call: the benchmark's check wraps `_step` in place
+            program = self._verify if self.speculate else self._step
+            (logits, self.cache), health.retries = self._device_step(
+                args, fail, program)
+            if S == 1:
+                # (B, vocab): the shape the benchmark warms the eager
+                # argmax at, so the window compiles nothing
+                logits = logits[:, -1]
+            best = None if self.abft else jnp.argmax(logits, axis=-1)
         with _Phase(health, "wait"):
             if self.abft:
                 # token derivation reads the VERIFIED host copy (np.argmax
                 # and jnp.argmax agree bitwise: both take the first maximal
                 # index)
-                last_host = self._abft_host_logits(last, now)
-                next_tok = np.argmax(last_host, axis=-1).astype(np.int32)
-            else:
-                next_tok = np.asarray(best, np.int32)
+                best = np.argmax(self._abft_host_logits(logits, now), axis=-1)
+            rows = np.asarray(best, np.int32).reshape(self.B, S)
             finite = None
             if self.guard:
-                host = np.array(last)  # copy: poisoning writes into it
+                host = np.array(logits)  # copy: poisoning writes into it
                 if self.chaos is not None:
                     victim = self.chaos.poison_slot(
                         now, [i for i, s in enumerate(self.slots)
                               if not s.free])
                     if victim is not None:
                         host[victim] = np.nan  # the fault the guard catches
-                finite = np.isfinite(host).all(axis=-1)
+                finite = np.isfinite(host).all(axis=-1).reshape(self.B, S)
         with _Phase(health, "emit"):
             for i, s in enumerate(self.slots):
                 if s.free:
                     continue
                 req = s.req
-                s.pos += 1
-                if finite is not None and not finite[i]:
+                fed, drafts = s.fed, s.drafts
+                if finite is not None and not (
+                        finite[i, :fed + len(drafts)].all()):
                     # quarantine: ONLY this slot fails; its pages are
                     # released and nothing it produced this step is kept
                     # or published
@@ -925,36 +975,76 @@ class ContinuousBatcher:
                     req.log_event("quarantined", now)
                     self._finish_slot(i, FinishReason.FAILED)
                     continue
-                # a slot that exhausted its page reservation (an over-long
-                # prompt) is truncated and evicted — capacity exhaustion
-                # must degrade, never crash the serving loop.  The dense
-                # rectangle has the same cap at max_len; the paged cap can
-                # be lower when the reservation was clipped to
-                # min(max_len, prompt + max_new).
-                out_of_room = self.paged and s.pos >= len(
-                    self.pool.owned(i)) * self.page_size
-                if s.prompt_left > 1:
-                    s.prompt_left -= 1  # still prefilling; ignore the logit
+                prefilling = s.prompt_left > 0
+                completes = fed >= s.prompt_left
+                # the last fed row scores the next token
+                out = [int(rows[i, fed - 1])] if completes else []
+                if drafts:  # each draft the argmax chain agrees with
+                    for j, t in enumerate(drafts):
+                        if t != out[-1]:
+                            break
+                        out.append(int(rows[i, fed + j]))
+                    agreed = len(out) - 1  # counted before EOS truncation
+                    self.spec.windows += 1
+                    self.spec.drafted += len(drafts)
+                    self.spec.accepted += agreed
+                    req.log_event(f"speculated:{agreed}/{len(drafts)}", now)
+                hit_eos = req.eos_id in out
+                if hit_eos:
+                    del out[out.index(req.eos_id) + 1:]
+                s.pos += fed
+                fed_end = s.pos
+                if len(out) > 1:  # the accepted drafts' rows
+                    s.pos += len(out) - 1
+                if prefilling:
+                    s.prompt_left -= fed
+                if self.paged:
+                    self.pool.set_length(i, s.pos)
+                # a slot that exhausted its room (an over-long prompt) is
+                # truncated and evicted — capacity exhaustion must degrade,
+                # never crash the serving loop
+                room = self._room(i)
+                out_of_room = s.pos >= room
+                if not completes:  # still prefilling: the logits go unused
                     if out_of_room:
                         self._finish_slot(i, FinishReason.TRUNCATED)
                     continue
-                if s.prompt_left == 1:
-                    s.prompt_left = 0  # prompt done: this logit starts (or,
-                    req.state = RequestState.DECODE  # on resume, continues)
-                    if self.prefix is not None and not out_of_room:
+                if prefilling:
+                    # prompt done: this logit starts (or, on resume,
+                    # continues) the answer
+                    req.state = RequestState.DECODE
+                    if self.prefix is not None and fed_end < room:
                         # the sequence's full pages are now immutable
                         # (decode continues in later pages): publish them
-                        # for reuse.  Pages the slot itself mounted shared
-                        # dedup inside the index (existing nodes win, no
-                        # double pin).
+                        # for reuse.  Whether it publishes is decided at
+                        # the last prompt row, whatever drafts follow.
+                        # Pages the slot itself mounted shared dedup
+                        # inside the index (existing nodes win, no double
+                        # pin).
                         self.prefix.insert(s.seq, self.pool.owned(i))
-                req.output.append(int(next_tok[i]))
-                health.emitted += 1
+                req.output.extend(out)
+                health.emitted += len(out)
                 if req.first_token_at is None:
                     req.first_token_at = now
                     req.log_event("first_token", now)
-                self._finish_if_done(i, s, req, req.output[-1] == req.eos_id,
-                                     out_of_room)
+                self._finish_if_done(i, s, req, hit_eos, out_of_room)
+            if self.speculate:
+                self.spec.launches += 1
+                self.spec.emitted += health.emitted
+
+    def _draft(self, i: int, s: _Slot, S: int) -> tuple:
+        """The drafter's proposals to ride behind slot i's last fed row.
+        They stay inside the reservation, max_len and the max_new budget:
+        the clamp is what makes every finish path land on the token it
+        lands on without speculation, and a draft longer than the room left
+        degrades to a shorter window instead of corrupting pages."""
+        k = min(S - s.fed, min(self._room(i), self.max_len) - s.pos - s.fed,
+                s.req.remaining_new() - 1)
+        if k <= 0:
+            return ()
+        prop = np.asarray(self.drafter.propose(s.req.sequence(), k),
+                          np.int32).reshape(-1)[:k]
+        return tuple(int(t) for t in prop)
 
     def _finish_if_done(self, i: int, s: _Slot, req: Request, hit_eos: bool,
                         out_of_room: bool):
@@ -967,169 +1057,6 @@ class ContinuousBatcher:
             self._finish_slot(i, FinishReason.MAX_LEN)
         elif out_of_room:
             self._finish_slot(i, FinishReason.TRUNCATED)
-
-    def _verify_window(self, health: StepHealth):
-        """One speculative verify step across all slots: a (B, k+1) token
-        window through `verify_step_paged` in ONE launch, then host-side
-        greedy-exact acceptance.
-
-        Window layout per active slot (S = speculate+1 rows, padded with
-        zeros — pad rows write into future positions of the slot's own
-        reserved pages or the dump page, both dead under the length mask):
-
-          - still prefilling: the next up-to-S prompt tokens, forced
-            (accepted by construction, like chunked prefill but through
-            the verify kernel).  If the window reaches the LAST prompt
-            row, up to k drafts ride behind it — the first emission and
-            its speculation share the launch.
-          - decoding: row 0 is the committed last output token, rows
-            1..k the drafter's proposals.
-
-        Acceptance publishes by advancing s.pos/pool length over rows
-        whose fed token is committed; a rejected draft's K/V rows are
-        simply never published — the zero-copy rollback (pages were
-        reserved worst-case at admission, so no page ever moves).  Every
-        finish path, the prefix-cache publish point, the non-finite
-        quarantine and the retry policy mirror the plain step exactly, so
-        the emitted argmax stream is bitwise-identical to speculate=0."""
-        now = health.step
-        S = self.speculate + 1
-        with _Phase(health, "prepare"):
-            tokens = np.zeros((self.B, S), np.int32)
-            index = np.zeros((self.B,), np.int32)
-            lengths = np.zeros((self.B,), np.int32)
-            meta = {}
-            for i, s in enumerate(self.slots):
-                if s.free:
-                    continue
-                req = s.req
-                cap = len(self.pool.owned(i)) * self.page_size
-                was_prefill = s.prompt_left > 0
-                if was_prefill:
-                    start = len(s.seq) - s.prompt_left
-                    take = min(S, s.prompt_left, cap - s.pos)
-                    tokens[i, :take] = s.seq[start:start + take]
-                    completes = take == s.prompt_left
-                else:
-                    take = 1
-                    tokens[i, 0] = req.output[-1]
-                    completes = True
-                kd = 0
-                drafts = ()
-                if completes:
-                    # drafts must stay inside the reservation, max_len and
-                    # the max_new budget — the clamp is what makes every
-                    # finish path land on the same token it lands on
-                    # without speculation (and "draft longer than remaining
-                    # room" degrade to a shorter window instead of
-                    # corrupting pages)
-                    kd = max(0, min(S - take,
-                                    min(cap, self.max_len) - s.pos - take,
-                                    req.remaining_new() - 1))
-                    if kd > 0:
-                        prop = np.asarray(
-                            self.drafter.propose(req.sequence(), kd),
-                            np.int32).reshape(-1)[:kd]
-                        kd = int(prop.size)
-                        if kd:
-                            tokens[i, take:take + kd] = prop
-                        drafts = tuple(int(t) for t in prop)
-                index[i] = s.pos
-                # the kernel's row-r mask is kpos <= lengths-S+r: passing
-                # pos+S makes row r attend exactly through its own position
-                lengths[i] = s.pos + S
-                meta[i] = (take, kd, drafts, completes, was_prefill, cap)
-            fail = self.chaos.wants_failure(now) if self.chaos else False
-            deepest = max(s.pos for s in self.slots if not s.free)
-            # window rows reach position pos+S-1, so the table must cover
-            # one window past the deepest slot (entries past a slot's owned
-            # pages render as the dump page — pad-row writes land there
-            # harmlessly)
-            health.width = _next_pow2(self.pool.pages_for(deepest + S))
-            health.attn_pages = self._attn_pages(lengths)
-            args = (self.params, jnp.asarray(tokens), self.cache,
-                    jnp.asarray(index),
-                    jnp.asarray(self.pool.page_table(self.B, health.width)),
-                    jnp.asarray(lengths))
-        with _Phase(health, "dispatch"):
-            (logits, self.cache), health.retries = self._device_step(
-                args, fail, fn=self._verify)
-            self.spec.launches += 1
-            best = None if self.abft else jnp.argmax(logits, axis=-1)
-        with _Phase(health, "wait"):
-            if self.abft:
-                win_host = self._abft_host_logits(logits, now)
-                rows = np.argmax(win_host, axis=-1).astype(np.int32)
-            else:
-                rows = np.asarray(best, np.int32)  # (B, S)
-            finite = None
-            if self.guard:
-                host = np.array(logits)  # copy: poisoning writes into it
-                if self.chaos is not None:
-                    victim = self.chaos.poison_slot(
-                        now, [i for i, s in enumerate(self.slots)
-                              if not s.free])
-                    if victim is not None:
-                        host[victim] = np.nan
-                finite = np.isfinite(host).all(axis=-1)  # (B, S)
-        with _Phase(health, "emit"):
-            for i, s in enumerate(self.slots):
-                if s.free:
-                    continue
-                req = s.req
-                take, kd, drafts, completes, was_prefill, cap = meta[i]
-                if finite is not None and not finite[i, :take + kd].all():
-                    health.poisoned.append(req.rid)
-                    req.log_event("quarantined", now)
-                    self._finish_slot(i, FinishReason.FAILED)
-                    continue
-                pos0 = s.pos
-                emitted: List[int] = []
-                a = 0  # drafts the model agreed with (pre-EOS-truncation)
-                if completes:
-                    emitted = [int(rows[i, take - 1])]
-                    for j in range(kd):
-                        if drafts[j] != emitted[-1]:
-                            break
-                        emitted.append(int(rows[i, take + j]))
-                        a += 1
-                hit_eos = False
-                if req.eos_id is not None:
-                    for j, t in enumerate(emitted):
-                        if t == req.eos_id:
-                            emitted = emitted[:j + 1]
-                            hit_eos = True
-                            break
-                a_kept = max(len(emitted) - 1, 0)
-                s.pos = pos0 + take + a_kept
-                if was_prefill:
-                    s.prompt_left -= take
-                self.pool.set_length(i, s.pos)
-                if kd > 0:
-                    self.spec.windows += 1
-                    self.spec.drafted += kd
-                    self.spec.accepted += a
-                    req.log_event(f"speculated:{a}/{kd}", now)
-                self.spec.emitted += len(emitted)
-                out_of_room = s.pos >= cap
-                if not completes:
-                    if out_of_room:
-                        self._finish_slot(i, FinishReason.TRUNCATED)
-                    continue
-                if was_prefill:
-                    req.state = RequestState.DECODE
-                    # plain-path publish condition, measured at the
-                    # position the LAST PROMPT row landed (accepted drafts
-                    # beyond it must not change whether the prefix
-                    # publishes)
-                    if self.prefix is not None and pos0 + take < cap:
-                        self.prefix.insert(s.seq, self.pool.owned(i))
-                req.output.extend(emitted)
-                health.emitted += len(emitted)
-                if req.first_token_at is None:
-                    req.first_token_at = now
-                    req.log_event("first_token", now)
-                self._finish_if_done(i, s, req, hit_eos, out_of_room)
 
     def _flush_health(self, health: StepHealth, ran_device_step: bool):
         dt = time.perf_counter() - health.t_start

@@ -176,6 +176,40 @@ def test_phases_are_profiler_spans(model_and_params, tmp_path):
     assert spans["batcher.prefill"] == [{"rid": 2, "tokens": 4}] * 3
 
 
+@pytest.mark.parametrize("speculate", [0, 2], ids=["decode", "speculative"])
+def test_each_step_span_holds_one_of_each_device_phase(model_and_params,
+                                                       tmp_path, speculate):
+    """The decode step and the speculative window run the same phases: one
+    ``batcher.step`` span holds exactly one span of each."""
+    from jax.profiler import ProfileData
+
+    cfg, model, params = model_and_params
+    b = ContinuousBatcher(model, params, batch_slots=2, max_len=32,
+                          paged=True, page_size=4, prefill_chunk=4,
+                          speculate=speculate)
+    prompts = np.random.default_rng(3).integers(0, cfg.vocab, (2, 8))
+    for rid, n in ((0, 3), (1, 8)):
+        b.submit(Request(rid=rid, prompt=prompts[rid, :n], max_new=16))
+    b.step()  # compiles outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(3):
+            b.step()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [e for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("batcher.")]
+    steps = [e for e in events if e.name == "batcher.step"]
+    assert len(steps) == 3
+    for step in steps:
+        inside = [e.name for e in events if e is not step
+                  and step.start_ns <= e.start_ns
+                  and e.end_ns <= step.end_ns]
+        assert sorted(inside) == sorted(
+            f"batcher.{p}"
+            for p in ("admit", "prepare", "dispatch", "wait", "emit")), inside
+
+
 def test_program_names_are_what_trace_readers_key_on(model_and_params,
                                                      monkeypatch):
     """The decode and prefill XLA modules hold the names the benchmark's
